@@ -339,23 +339,22 @@ class FileCheckpointer:
             # output localizes dirty tiles (driving both the delta plan
             # and the device gather) and folds into the scalar leaf
             # digest, so one pass serves both.
+            from repro.kernels.checksum.ops import (
+                checksum_words_device, device_digestible,
+                tile_checksums_device)
+            # exotic itemsizes stay out and take the host path
+            dev = {k: v for k, v in snap.items()
+                   if isinstance(v, jax.Array) and device_digestible(v)}
             if self._delta_on:
-                from repro.kernels.checksum.ops import tile_checksums_device
-                dev_tiles = {}
-                for k, v in snap.items():
-                    if isinstance(v, jax.Array):
-                        try:
-                            dev_tiles[k] = (str(v.dtype), tuple(v.shape),
-                                            int(v.nbytes),
-                                            tile_checksums_device(v))
-                        except TypeError:     # exotic itemsize: host path
-                            pass
+                dev_tiles = {
+                    k: (str(v.dtype), tuple(v.shape), int(v.nbytes),
+                        tile_checksums_device(v))
+                    for k, v in dev.items()}
             else:
-                from repro.kernels.checksum.ops import checksum_words_device
                 dev_sums = {
                     k: (str(v.dtype), tuple(v.shape),
                         checksum_words_device(v))
-                    for k, v in snap.items() if isinstance(v, jax.Array)}
+                    for k, v in dev.items()}
         if async_:
             fut = self._writer_pool().submit(
                 self._write_guarded, step, snap, dev_sums, dev_tiles,
@@ -435,16 +434,16 @@ class FileCheckpointer:
         the delta frame writers consume. Only gathered tiles (O(dirt))
         and plan-full leaves ever cross; clean bytes stay on device."""
         from repro.kernels.checksum.ref import TILE_BYTES
-        from repro.kernels.checksum.ops import gather_tiles_device
+        from repro.kernels.checksum.ops import (device_digestible,
+                                                gather_tiles_device)
         dev = {}
         for k, rng in plan.entries.items():
             v = snap[k]
-            if rng is None or not isinstance(v, jax.Array):
+            # exotic itemsizes take the host slices below
+            if rng is None or not isinstance(v, jax.Array) \
+                    or not device_digestible(v):
                 continue
-            try:
-                g = gather_tiles_device(v, serde.range_tiles(rng))
-            except TypeError:        # exotic itemsize: host slices below
-                continue
+            g = gather_tiles_device(v, serde.range_tiles(rng))
             try:
                 g.copy_to_host_async()
             except (AttributeError, RuntimeError):

@@ -41,8 +41,6 @@ def buddy_exchange(state, mesh: Mesh, rules: ShardingRules,
     """Returns the buddy copy of `state`: each data-shard moved one step
     (cyclically) along `axis`. Leaves not sharded on `axis` come back
     unchanged (they are already replicated = already redundant)."""
-    from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis]
     if n == 1:
         return state
@@ -53,8 +51,8 @@ def buddy_exchange(state, mesh: Mesh, rules: ShardingRules,
         return jax.tree.map(
             lambda a: jax.lax.ppermute(a, axis, perm), st)
 
-    return shard_map(fn, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                     check_rep=False)(state)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(specs,),
+                         out_specs=specs, check_vma=False)(state)
 
 
 def restore_from_buddy(buddy_state, mesh: Mesh, rules: ShardingRules,
@@ -63,8 +61,6 @@ def restore_from_buddy(buddy_state, mesh: Mesh, rules: ShardingRules,
 
     After a shard loss, the survivor copies plus the buddy ring reconstruct
     every shard (single-failure guarantee, as in the paper)."""
-    from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis]
     if n == 1:
         return buddy_state
@@ -75,8 +71,8 @@ def restore_from_buddy(buddy_state, mesh: Mesh, rules: ShardingRules,
         return jax.tree.map(
             lambda a: jax.lax.ppermute(a, axis, perm), st)
 
-    return shard_map(fn, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                     check_rep=False)(buddy_state)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(specs,),
+                         out_specs=specs, check_vma=False)(buddy_state)
 
 
 class _Spilled:

@@ -1,17 +1,29 @@
-"""Pallas TPU tiled-reduction checksum kernel.
+"""Pallas TPU kernels for the checkpoint digests and the dirty-tile gather.
 
-Computes the (s0, s1) word-sums of `ref.py` over a uint32 word stream
-entirely on device: the words are tiled into (block_rows, 128) VMEM
-stripes, the grid walks the stripes sequentially ("arbitrary" semantics),
-and two (1, 1) SMEM scalars accumulate
+The digests are the (s0, s1) word-sums and per-tile (s0, s1, m) rows of
+`ref.py`, defined over a uint32 word stream mod 2^32. The TPU's vector
+units reduce signed integers only, so the kernels bitcast the words to
+int32 and compute in int32: two's-complement add and multiply wrap mod
+2^32 exactly like uint32 arithmetic, so the bits are the same. The
+results are bitcast back to uint32 outside the kernel.
 
-    s0 += sum(tile)
-    s1 += sum(tile * (global_word_index + 1))      (all mod 2^32)
+Every block obeys the (8, 128) tiling rule and every output is
+lane-dense:
 
-Only the two 4-byte scalars ever cross back to the host — the checkpoint
-path never materializes a host-side `tobytes()` copy just to hash it.
-uint32 arithmetic wraps mod 2^32 natively, which is exactly the checksum's
-definition, so no masking is needed on device.
+  checksum_kernel        walks (block_rows, 128) stripes of the stream on
+                         a sequential grid and accumulates per-lane sums
+                         in two resident (1, 128) output blocks; XLA folds
+                         the 128 lanes afterwards.
+  tile_checksum_kernel   one grid step digests 128 tiles of (8, 128)
+                         words: sublane sums give (tile, lane) partials,
+                         a transpose puts the tiles on lanes, and the lane
+                         reduction writes one (8, 128) block whose rows
+                         0..2 are the s0, s1, m columns of those tiles.
+  gather_tiles_kernel    scalar-prefetched tile indices drive the input
+                         index map: grid step i DMAs tile idx[i].
+
+Only the digests (8 B per leaf, 12 B per tile) or the gathered dirty
+tiles ever cross back to the host.
 """
 from __future__ import annotations
 
@@ -19,12 +31,28 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
+from .ref import MIX_MULS, TILE_WORDS
 
 _COLS = 128
+_ROWS_PER_TILE = TILE_WORDS // _COLS            # 8
+# tiles digested per grid step: puts one tile per lane after the transpose
+_TILES_PER_BLOCK = _COLS
+_MIX_MULS_I32 = tuple(int(np.uint32(m).view(np.int32)) for m in MIX_MULS)
+# tiles per gather call: its prefetched indices (256 KiB) must fit the
+# 1 MiB of scalar memory
+_GATHER_CHUNK = 1 << 16
+
+
+def _as_i32(words):
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def _as_u32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.uint32)
 
 
 def _checksum_kernel(w_ref, s0_ref, s1_ref, *, block_rows: int):
@@ -32,33 +60,58 @@ def _checksum_kernel(w_ref, s0_ref, s1_ref, *, block_rows: int):
 
     @pl.when(gi == 0)
     def _init():
-        s0_ref[0, 0] = jnp.uint32(0)
-        s1_ref[0, 0] = jnp.uint32(0)
+        s0_ref[...] = jnp.zeros_like(s0_ref)
+        s1_ref[...] = jnp.zeros_like(s1_ref)
 
     w = w_ref[...]                                   # (block_rows, 128)
-    base = jnp.uint32(block_rows * _COLS) * gi.astype(jnp.uint32)
-    row = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, _COLS), 0)
-    col = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, _COLS), 1)
-    idx = base + row * jnp.uint32(_COLS) + col + jnp.uint32(1)
-    s0_ref[0, 0] += jnp.sum(w, dtype=jnp.uint32)
-    s1_ref[0, 0] += jnp.sum(w * idx, dtype=jnp.uint32)
+    row = jax.lax.broadcasted_iota(jnp.int32, w.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+    idx = (gi * block_rows + row) * _COLS + col + 1  # global word index + 1
+    s0_ref[...] += jnp.sum(w, axis=0, keepdims=True)
+    s1_ref[...] += jnp.sum(w * idx, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def checksum_kernel(words, *, block_rows: int = 512,
+                    interpret: bool = False):
+    """words: 1-D uint32 → (s0, s1) uint32 device scalars.
+
+    Zero padding up to whole blocks adds nothing to either sum. The
+    block is clamped to the (8-row aligned) stream, so a small leaf runs
+    as one grid step."""
+    n = words.size
+    rows = -(-n // _COLS)
+    block_rows = min(block_rows, -(-rows // 8) * 8)
+    rows_pad = -(-rows // block_rows) * block_rows
+    w2 = _as_i32(jnp.pad(words, (0, rows_pad * _COLS - n))) \
+        .reshape(rows_pad, _COLS)
+    lanes = pl.BlockSpec((1, _COLS), lambda i: (0, 0))
+    s0, s1 = pl.pallas_call(
+        functools.partial(_checksum_kernel, block_rows=block_rows),
+        grid=(rows_pad // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, _COLS), lambda i: (i, 0))],
+        out_specs=[lanes, lanes],
+        out_shape=[jax.ShapeDtypeStruct((1, _COLS), jnp.int32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(w2)
+    return _as_u32(jnp.sum(s0)), _as_u32(jnp.sum(s1))
 
 
 def _tile_checksum_kernel(w_ref, out_ref):
-    """One grid step = one 4 KB tile = one (8, 128) block: emit the
-    tile's standalone (s0, s1, m) digest row — the local-weighted
-    word-sum pair plus the nonlinear xor-shift-multiply mix column (the
-    delta checkpointer compares these rows across consecutive
-    snapshots)."""
-    from .ref import MIX_C
-    w = w_ref[...]                                   # (8, 128)
-    row = jax.lax.broadcasted_iota(jnp.uint32, w.shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.uint32, w.shape, 1)
-    idx = row * jnp.uint32(_COLS) + col + jnp.uint32(1)
-    mixed = (w ^ (w >> jnp.uint32(16))) * jnp.uint32(MIX_C)
-    out_ref[0, 0] = jnp.sum(w, dtype=jnp.uint32)
-    out_ref[0, 1] = jnp.sum(w * idx, dtype=jnp.uint32)
-    out_ref[0, 2] = jnp.sum(mixed, dtype=jnp.uint32)
+    w = w_ref[...]                                   # (tiles, 8, 128)
+    row = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, w.shape, 2)
+    idx = row * _COLS + col + 1                      # word index in tile + 1
+    mixed = w
+    for shift, mul in zip((16, 13), _MIX_MULS_I32):    # murmur3 finalizer
+        mixed = (mixed ^ jax.lax.shift_right_logical(mixed, shift)) * mul
+    mixed = mixed ^ jax.lax.shift_right_logical(mixed, 16)
+    out_ref[...] = jnp.zeros_like(out_ref)
+    for c, v in enumerate((w, w * idx, mixed)):
+        part = jnp.sum(v, axis=1)                    # (tile, lane)
+        out_ref[c:c + 1, :] = jnp.sum(part.T, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -70,23 +123,24 @@ def tile_checksum_kernel(words, *, interpret: bool = False):
     independent ("parallel" semantics); only 12 bytes per tile — 0.3% of
     the data — ever leave the device.
     """
-    from .ref import TILE_WORDS
-    rows_per_tile = TILE_WORDS // _COLS              # 8
     n = words.size
     nt = max(1, -(-n // TILE_WORDS))
-    w2 = jnp.pad(words, (0, nt * TILE_WORDS - n)) \
-        .reshape(nt * rows_per_tile, _COLS)
-    return pl.pallas_call(
+    nb = -(-nt // _TILES_PER_BLOCK)
+    ntp = nb * _TILES_PER_BLOCK
+    w3 = _as_i32(jnp.pad(words, (0, ntp * TILE_WORDS - n))) \
+        .reshape(ntp, _ROWS_PER_TILE, _COLS)
+    out = pl.pallas_call(
         _tile_checksum_kernel,
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((rows_per_tile, _COLS), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 3), lambda i: (i, 0),
-                               memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((nt, 3), jnp.uint32),
-        compiler_params=CompilerParams(
+        grid=(nb,),
+        in_specs=[pl.BlockSpec((_TILES_PER_BLOCK, _ROWS_PER_TILE, _COLS),
+                               lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec((8, _TILES_PER_BLOCK), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((8, ntp), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(w2)
+    )(w3)
+    return _as_u32(out[:3, :nt].T)
 
 
 def _gather_tiles_kernel(idx_ref, in_ref, out_ref):
@@ -96,6 +150,27 @@ def _gather_tiles_kernel(idx_ref, in_ref, out_ref):
     out_ref[...] = in_ref[...]
 
 
+def _gather_call(tiles, idx, interpret: bool):
+    k = idx.shape[0]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(k,),
+        in_specs=[pl.BlockSpec((_ROWS_PER_TILE, _COLS),
+                               lambda i, idx_ref: (idx_ref[i], 0))],
+        out_specs=pl.BlockSpec((_ROWS_PER_TILE, _COLS),
+                               lambda i, idx_ref: (i, 0)),
+    )
+    return pl.pallas_call(
+        _gather_tiles_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((k * _ROWS_PER_TILE, _COLS),
+                                       jnp.uint32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(idx, tiles)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_tiles_kernel(tiles, idx, *, interpret: bool = False):
     """tiles: (nt*8, 128) uint32 word rows; idx: (k,) int32 ascending
@@ -103,58 +178,12 @@ def gather_tiles_kernel(tiles, idx, *, interpret: bool = False):
 
     The dirty-tile indices are scalar-prefetched so the input BlockSpec's
     index map can read them: grid step i DMAs exactly the (8, 128) block
-    of tile idx[i] from HBM and streams it to output block i. Only the
-    gathered tiles ever move — the D2H copy that follows is O(dirt), not
-    O(state).
+    of tile idx[i] from HBM and streams it to output block i. More than
+    _GATHER_CHUNK tiles are gathered in several calls. Only the gathered
+    tiles ever move — the D2H copy that follows is O(dirt), not O(state).
     """
-    from .ref import TILE_WORDS
-    rows_per_tile = TILE_WORDS // _COLS              # 8
     k = idx.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(k,),
-        in_specs=[pl.BlockSpec((rows_per_tile, _COLS),
-                               lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((rows_per_tile, _COLS),
-                               lambda i, idx_ref: (i, 0)),
-    )
-    out = pl.pallas_call(
-        _gather_tiles_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k * rows_per_tile, _COLS),
-                                       jnp.uint32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(idx, tiles)
+    parts = [_gather_call(tiles, idx[i:i + _GATHER_CHUNK], interpret)
+             for i in range(0, k, _GATHER_CHUNK)]
+    out = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
     return out.reshape(k, TILE_WORDS)
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def checksum_kernel(words, *, block_rows: int = 8, interpret: bool = False):
-    """words: 1-D uint32 → (s0, s1) uint32 device scalars."""
-    n = words.size
-    rows = -(-n // _COLS)
-    rows_pad = -(-rows // block_rows) * block_rows
-    w2 = jnp.pad(words, (0, rows_pad * _COLS - n)).reshape(rows_pad, _COLS)
-
-    kernel = functools.partial(_checksum_kernel, block_rows=block_rows)
-    s0, s1 = pl.pallas_call(
-        kernel,
-        grid=(rows_pad // block_rows,),
-        in_specs=[pl.BlockSpec((block_rows, _COLS), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((1, 1), jnp.uint32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(w2)
-    return s0[0, 0], s1[0, 0]

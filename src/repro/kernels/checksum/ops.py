@@ -3,8 +3,9 @@
 `leaf_checksum` routes each leaf to the cheapest correct implementation:
 
   - host numpy arrays       → vectorized numpy reference (no tobytes copy)
-  - device jax arrays, TPU  → Pallas tiled-reduction kernel (on-device)
-  - device jax arrays, else → jitted jnp reduction (same math, same wrap)
+  - jax arrays on one TPU   → Pallas tiled-reduction kernel (on-device)
+  - other jax arrays        → jitted jnp reduction (same math, same wrap);
+                              this includes arrays sharded over a mesh
 
 All three compute the identical (s0, s1) word-sum pair defined in
 `ref.py`; parity is asserted in tests/test_checksum.py.
@@ -17,10 +18,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .ref import TILE_WORDS, checksum_words_ref, tile_checksums_ref
+from .ref import (MIX_MULS, TILE_WORDS, checksum_words_ref,
+                  tile_checksums_ref)
 
 # Below this many words a kernel launch costs more than it saves.
 _PALLAS_MIN_WORDS = 1 << 15
+
+
+def _pallas_path(words: jax.Array) -> bool:
+    """The Pallas kernels run on a TPU, for streams long enough to pay
+    for a launch, held by one device: a Mosaic kernel cannot be
+    partitioned over a mesh, so a sharded or replicated stream takes the
+    jnp reduction, which XLA partitions."""
+    return (jax.default_backend() == "tpu"
+            and words.size >= _PALLAS_MIN_WORDS
+            and len(words.sharding.device_set) == 1)
+
+
+def device_digestible(x) -> bool:
+    """True when the device path can turn `x` into a word stream (every
+    dtype of 1, 2, 4 or 8 bytes); others digest on the host."""
+    return x.dtype.itemsize in (1, 2, 4, 8)
 
 
 def _device_words(x: jax.Array) -> jax.Array:
@@ -69,8 +87,7 @@ def checksum_words(x, *, interpret: bool = False) -> tuple[int, int]:
     words = _device_words(jnp.asarray(x))
     if words.size == 0:
         return 0, 0
-    if interpret or (jax.default_backend() == "tpu"
-                     and words.size >= _PALLAS_MIN_WORDS):
+    if interpret or _pallas_path(words):
         # lazy: host-only digest paths never pay the pallas import
         from .kernel import checksum_kernel
         s0, s1 = checksum_kernel(words, interpret=interpret)
@@ -87,8 +104,7 @@ def checksum_words_device(x: jax.Array):
     words = _device_words(jnp.asarray(x))
     if words.size == 0:
         return None
-    if (jax.default_backend() == "tpu"
-            and words.size >= _PALLAS_MIN_WORDS):
+    if _pallas_path(words):
         from .kernel import checksum_kernel
         return checksum_kernel(words)
     return _wordsum_jnp(words)
@@ -96,12 +112,13 @@ def checksum_words_device(x: jax.Array):
 
 @jax.jit
 def _tilesum_jnp(words):
-    from .ref import MIX_C
     n = words.size
     nt = max(1, -(-n // TILE_WORDS))
     w = jnp.pad(words, (0, nt * TILE_WORDS - n)).reshape(nt, TILE_WORDS)
     idx = jnp.arange(1, TILE_WORDS + 1, dtype=jnp.uint32)
-    mixed = (w ^ (w >> jnp.uint32(16))) * jnp.uint32(MIX_C)
+    mixed = (w ^ (w >> jnp.uint32(16))) * jnp.uint32(MIX_MULS[0])
+    mixed = (mixed ^ (mixed >> jnp.uint32(13))) * jnp.uint32(MIX_MULS[1])
+    mixed = mixed ^ (mixed >> jnp.uint32(16))
     s0 = jnp.sum(w, axis=1, dtype=jnp.uint32)
     s1 = jnp.sum(w * idx, axis=1, dtype=jnp.uint32)
     m = jnp.sum(mixed, axis=1, dtype=jnp.uint32)
@@ -118,8 +135,7 @@ def tile_checksums_device(x, *, interpret: bool = False):
     words = _device_words(jnp.asarray(x))
     if words.size == 0:
         return None
-    if interpret or (jax.default_backend() == "tpu"
-                     and words.size >= _PALLAS_MIN_WORDS):
+    if interpret or _pallas_path(words):
         from .kernel import tile_checksum_kernel
         return tile_checksum_kernel(words, interpret=interpret)
     return _tilesum_jnp(words)
@@ -150,8 +166,7 @@ def gather_tiles_device(x, idx, *, interpret: bool = False) -> jax.Array:
     """
     tiles2d = _device_tiles2d(x)
     idx = jnp.asarray(np.asarray(idx, np.int32))
-    if interpret or (jax.default_backend() == "tpu"
-                     and tiles2d.size >= _PALLAS_MIN_WORDS):
+    if interpret or _pallas_path(tiles2d):
         from .kernel import gather_tiles_kernel
         return gather_tiles_kernel(
             tiles2d.reshape(-1, 128), idx, interpret=interpret)
@@ -162,21 +177,14 @@ def tile_checksums(arr) -> np.ndarray:
     """Type-dispatching per-tile digest entry point (host ndarray out):
     device arrays stay on device for the reduction, host arrays go through
     the vectorized numpy reference."""
-    if isinstance(arr, jax.Array):
-        try:
-            t = tile_checksums_device(arr)
-            return np.zeros((0, 3), np.uint32) if t is None \
-                else np.asarray(t)
-        except TypeError:       # exotic itemsize — fall through to host
-            pass
+    if isinstance(arr, jax.Array) and device_digestible(arr):
+        t = tile_checksums_device(arr)
+        return np.zeros((0, 3), np.uint32) if t is None else np.asarray(t)
     return tile_checksums_ref(np.asarray(arr))
 
 
 def leaf_checksum(arr) -> tuple[int, int]:
     """Type-dispatching entry point used by checkpoint.manifest."""
-    if isinstance(arr, jax.Array):
-        try:
-            return checksum_words(arr)
-        except TypeError:       # exotic itemsize — fall through to host
-            pass
+    if isinstance(arr, jax.Array) and device_digestible(arr):
+        return checksum_words(arr)
     return checksum_words_ref(np.asarray(arr))

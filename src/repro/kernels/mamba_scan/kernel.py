@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, hout_ref, h_ref,
                  *, chunk: int, num_chunks: int):
@@ -95,7 +93,7 @@ def selective_scan(x, dt, B, C, A, *, chunk: int = 256, block_d: int = 512,
             jax.ShapeDtypeStruct((bsz, di, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, ds), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, B, C, A)

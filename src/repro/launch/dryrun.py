@@ -23,7 +23,7 @@ from repro.sharding.partition import (_divisible, constraint_scope,
 from repro.sharding.rules import PRESETS           # noqa: E402
 from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update  # noqa: E402
 from repro.launch.hlo_analysis import (  # noqa: E402
-    collective_summary, compiled_cost_analysis, while_report)
+    collective_summary, while_report)
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
@@ -219,7 +219,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.monotonic() - t0
         mem = compiled.memory_analysis()
-        ca = compiled_cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         hlo = compiled.as_text()
         colls = collective_summary(hlo)
         whiles = while_report(hlo)
@@ -227,8 +227,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                moe_group=ec.moe_group)
         result = {
             "arch": arch, "shape": shape_name, "mesh": mesh_name,
-            "chips": mesh.size if hasattr(mesh, "size") else
-            int(jnp.prod(jnp.array(list(mesh.shape.values())))),
+            "chips": mesh.size,
             "exec_config": dataclasses.asdict(ec),
             "lower_s": meta["lower_s"], "compile_s": t_compile,
             "memory": {

@@ -106,15 +106,6 @@ def _trip_count(cond_text: str, while_line_rest: str = "") -> int:
     return max(consts) if consts else 1
 
 
-def compiled_cost_analysis(compiled) -> dict:
-    """Version-compat: `Compiled.cost_analysis()` returns a per-device list
-    of dicts on older jax and a plain dict on newer; normalize to a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def analyze_collectives(hlo: str) -> List[CollectiveInfo]:
     comps = _split_computations(hlo)
 

@@ -4,23 +4,17 @@ from __future__ import annotations
 
 import jax
 
-# jax added `jax.sharding.AxisType` + the `axis_types=` kwarg on
-# `jax.make_mesh` after 0.4.x; support both (pattern: kernels/_compat.py).
-_AxisType = getattr(jax.sharding, "AxisType", None)
 
-
-def _compat_make_mesh(shape, axes):
-    if _AxisType is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(_AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(shape=(1,), axes=("data",)):
@@ -30,4 +24,4 @@ def make_host_mesh(shape=(1,), axes=("data",)):
         n *= s
     if n > len(jax.devices()):
         raise RuntimeError(f"need {n} devices, have {len(jax.devices())}")
-    return _compat_make_mesh(shape, axes)
+    return _make_mesh(shape, axes)

@@ -22,9 +22,11 @@ def main(argv=None):
 
     import jax
     from repro.configs import get_config, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import Model
     from repro.serve import Request, ServeEngine
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -58,10 +58,12 @@ def main(argv=None):
 
     from repro.configs import get_config, reduced
     from repro.core import FaultInjector, FailureType
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import Model
     from repro.train import (AdamWConfig, TokenPipeline, TrainConfig,
                              Trainer)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

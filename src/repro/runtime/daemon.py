@@ -29,6 +29,7 @@ import time
 
 from repro.core.failure import ChildMonitor
 
+from . import child_env
 from .transport import connect, listener, recv_msg, send_msg
 
 
@@ -141,7 +142,7 @@ class Daemon:
             cmd.append("--restarted")
         if shadow:
             cmd.append("--shadow")
-        env = dict(os.environ, PYTHONPATH=a.pythonpath)
+        env = child_env(a.pythonpath)
         proc = subprocess.Popen(cmd, env=env)
         with self.lock:
             self.workers[rank] = proc

@@ -63,6 +63,7 @@ from repro.core.recovery import STRATEGIES
 from repro.scenarios.schema import GRAY_DRAIN_PERSIST, GRAY_HOWS, \
     ROOT_INJECTED_EXIT, Scenario, gray_delay_s
 
+from . import child_env
 from .transport import connect, listener, recv_msg, send_msg
 
 # every registered strategy the live process tree can execute; ulfm is
@@ -244,7 +245,7 @@ class Root:
                "--hb-timeout", str(getattr(a, "hb_timeout", 0.0)),
                "--standby-port", str(self._standby_port),
                "--ckpt-dir", a.ckpt_dir, "--pythonpath", a.pythonpath]
-        env = dict(os.environ, PYTHONPATH=a.pythonpath)
+        env = child_env(a.pythonpath)
         self.daemon_procs[node] = subprocess.Popen(cmd, env=env)
 
     def deploy(self):
@@ -282,7 +283,7 @@ class Root:
                "--ckpt-dir", a.ckpt_dir, "--report", a.report,
                "--pythonpath", a.pythonpath,
                "--as-standby", "--primary-port", str(self.port)]
-        env = dict(os.environ, PYTHONPATH=a.pythonpath)
+        env = child_env(a.pythonpath)
         self.standby_proc = subprocess.Popen(cmd, env=env)
         if not self._standby_ready.wait(timeout=30):
             raise TimeoutError("standby root never registered")

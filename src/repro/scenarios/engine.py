@@ -21,6 +21,8 @@ import subprocess
 import sys
 from typing import Optional
 
+from repro.runtime import child_env
+
 from .schema import (ROOT_INJECTED_EXIT, Scenario, expected_resume_steps,
                      normalize_strategy)
 
@@ -134,7 +136,7 @@ def run_real(scenario: Scenario, strategy: str, workdir: str, *,
     os.makedirs(ckpt_dir, exist_ok=True)
     report_path = os.path.join(workdir, "report.json")
     cmd = _root_cmd(scenario_path, scenario, mode, ckpt_dir, report_path)
-    env = dict(os.environ, PYTHONPATH=SRC)
+    env = child_env(SRC)
 
     if os.path.exists(report_path):
         os.remove(report_path)

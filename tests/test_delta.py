@@ -56,6 +56,20 @@ def test_tile_digest_localizes_change():
     assert list(changed) == [False, True, False, False]
 
 
+@pytest.mark.parametrize("factor", [0.5, 2.0])
+def test_tile_digest_sees_uniform_exponent_shift(factor):
+    # one tile of floats in [1, 2): every word shares exponent 127, and
+    # exactly half have mantissa bit 7 set, the case where one
+    # xor-shift-multiply round cancels a halving to the same digest
+    rng = np.random.default_rng(7)
+    n = TILE_BYTES // 4
+    mant = rng.integers(0, 1 << 23, n, dtype=np.uint32) & ~np.uint32(1 << 7)
+    mant[rng.permutation(n)[:n // 2]] |= np.uint32(1 << 7)
+    a = (np.uint32(0x3F800000) | mant).view(np.float32)
+    b = (a * np.float32(factor)).astype(np.float32)
+    assert np.any(tile_checksums_ref(a) != tile_checksums_ref(b))
+
+
 def test_tile_digest_device_parity():
     from repro.kernels.checksum.ops import tile_checksums
     rng = np.random.default_rng(5)

@@ -48,8 +48,7 @@ def test_analytic_flops_vs_cost_analysis(arch):
             return model.loss_fn(p, b)[0]
         params = model.abstract_params()
         comp = jax.jit(fwd).lower(params, batch).compile()
-        from repro.launch.hlo_analysis import compiled_cost_analysis
-        measured = compiled_cost_analysis(comp)["flops"]
+        measured = comp.cost_analysis()["flops"]
         analytic = forward_flops(cfg, B, S, flash=False)
         ratio = analytic / measured
         print("RATIO", ratio)

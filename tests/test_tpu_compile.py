@@ -1,0 +1,95 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e.
+
+Nothing runs: the TPU compiler, which is installed without a chip,
+lowers each kernel at the widths the chip path uses and refuses what
+the chip would refuse (block tiling, unsupported reductions, fast
+memory), which interpret mode never checks. The topology is described
+inside a fixture, so only the worker that runs these tests loads the
+TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+
+PAPER_DEMO = get_config("paper-demo")
+QWEN2 = get_config("qwen2-7b")
+FALCON_MAMBA = get_config("falcon-mamba-7b")
+# words of one paper-demo embedding leaf (32768 x 768 float32)
+EMBED_WORDS = PAPER_DEMO.vocab_size * PAPER_DEMO.d_model
+D_INNER = FALCON_MAMBA.ssm_expand * FALCON_MAMBA.d_model      # 8192
+SEQ = 2048
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no TPU compiler to describe it with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def test_checksum_kernel_compiles(one_chip):
+    from repro.kernels.checksum.kernel import checksum_kernel
+    text = _compiled_text(checksum_kernel,
+                          _shape(one_chip, (EMBED_WORDS,), jnp.uint32))
+    assert "tpu_custom_call" in text
+
+
+def test_tile_checksum_kernel_compiles(one_chip):
+    from repro.kernels.checksum.kernel import tile_checksum_kernel
+    text = _compiled_text(tile_checksum_kernel,
+                          _shape(one_chip, (EMBED_WORDS,), jnp.uint32))
+    assert "tpu_custom_call" in text
+
+
+# half the tiles of a paper-demo embedding leaf dirty; every tile of a
+# qwen2-7b embedding leaf (532,224 tiles) dirty, gathered in chunks
+@pytest.mark.parametrize("words,dirty", [
+    (EMBED_WORDS, 0.5),
+    (QWEN2.vocab_size * QWEN2.d_model, 1.0)])
+def test_gather_tiles_kernel_compiles(one_chip, words, dirty):
+    from repro.kernels.checksum.kernel import gather_tiles_kernel
+    from repro.kernels.checksum.ref import TILE_WORDS
+    n_dirty = int(words // TILE_WORDS * dirty)
+    text = _compiled_text(
+        gather_tiles_kernel,
+        _shape(one_chip, (words // 128, 128), jnp.uint32),
+        _shape(one_chip, (n_dirty,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_forward_compiles(one_chip):
+    from repro.kernels.flash_attention.ops import flash_attention
+    hd = QWEN2.head_dim
+    q = _shape(one_chip, (1, SEQ, QWEN2.n_heads, hd), jnp.bfloat16)
+    kv = _shape(one_chip, (1, SEQ, QWEN2.n_kv_heads, hd), jnp.bfloat16)
+    text = _compiled_text(lambda q, k, v: flash_attention(q, k, v),
+                          q, kv, kv)
+    assert "tpu_custom_call" in text
+
+
+def test_selective_scan_compiles(one_chip):
+    from repro.kernels.mamba_scan.kernel import selective_scan
+    ds = FALCON_MAMBA.ssm_state
+    x = _shape(one_chip, (1, SEQ, D_INNER), jnp.float32)
+    bc = _shape(one_chip, (1, SEQ, ds), jnp.float32)
+    a = _shape(one_chip, (D_INNER, ds), jnp.float32)
+    text = _compiled_text(selective_scan, x, x, bc, bc, a)
+    assert "tpu_custom_call" in text
