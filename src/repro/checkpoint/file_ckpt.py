@@ -86,6 +86,7 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
+from repro.core.spans import span
 from repro.scenarios import hooks
 
 from . import serde
@@ -304,6 +305,10 @@ class FileCheckpointer:
         writer thread. Up to one snapshot queues behind the one draining
         (double buffering); further saves block on the oldest.
         """
+        with span("ckpt.save", step=step):
+            self._save(step, state, async_, extra)
+
+    def _save(self, step: int, state: Any, async_: bool, extra):
         self._raise_pending_error()
         if self.fmt == "npz":
             # legacy comparison path: host materialize + sha256
@@ -311,24 +316,26 @@ class FileCheckpointer:
             flat = flatten_state(state)
             self._write(step, flat, None, extra)
             return
-        if async_:
-            while len(self._pending) >= 2:   # double-buffer bound
-                self._pending.popleft().result()
-                self._raise_pending_error()
-        else:
-            # drain queued writes only — an in-flight background re-base
-            # must never stall the save path
-            self._drain_writes()
-        dev_flat = flatten_leaves(state)
-        # kick the full drain only when the planner is certain this save
-        # is a base (or the gather path is off) — a delta save will move
-        # just its gathered dirty tiles
-        kick = not self._gather_on or self._chain.predict_full(step)
-        if async_:
-            snap = {k: _snapshot_device(v, kick=kick)
-                    for k, v in dev_flat.items()}
-        else:
-            snap = dev_flat   # sync blocks: no donation hazard, no copy
+        with span("ckpt.backpressure"):
+            if async_:
+                while len(self._pending) >= 2:   # double-buffer bound
+                    self._pending.popleft().result()
+                    self._raise_pending_error()
+            else:
+                # drain queued writes only — an in-flight background
+                # re-base must never stall the save path
+                self._drain_writes()
+        with span("ckpt.snapshot"):
+            dev_flat = flatten_leaves(state)
+            # kick the full drain only when the planner is certain this
+            # save is a base (or the gather path is off) — a delta save
+            # will move just its gathered dirty tiles
+            kick = not self._gather_on or self._chain.predict_full(step)
+            if async_:
+                snap = {k: _snapshot_device(v, kick=kick)
+                        for k, v in dev_flat.items()}
+            else:
+                snap = dev_flat   # sync blocks: no donation hazard, no copy
         dev_sums = dev_tiles = None
         if self._device_digests_on:
             # digest on device from the snapshot — the word-sum
@@ -340,21 +347,26 @@ class FileCheckpointer:
             # and the device gather) and folds into the scalar leaf
             # digest, so one pass serves both.
             from repro.kernels.checksum.ops import (
-                checksum_words_device, device_digestible,
+                checksum_words_device, device_digestible, digest_bytes,
                 tile_checksums_device)
             # exotic itemsizes stay out and take the host path
             dev = {k: v for k, v in snap.items()
                    if isinstance(v, jax.Array) and device_digestible(v)}
-            if self._delta_on:
-                dev_tiles = {
-                    k: (str(v.dtype), tuple(v.shape), int(v.nbytes),
-                        tile_checksums_device(v))
-                    for k, v in dev.items()}
-            else:
-                dev_sums = {
-                    k: (str(v.dtype), tuple(v.shape),
-                        checksum_words_device(v))
-                    for k, v in dev.items()}
+            # bytes: the word streams digested on the device;
+            # kernel_bytes: the part of them a Pallas kernel takes
+            sizes = [digest_bytes(v) for v in dev.values()]
+            with span("ckpt.digest", bytes=sum(b for b, _ in sizes),
+                      kernel_bytes=sum(k for _, k in sizes)):
+                if self._delta_on:
+                    dev_tiles = {
+                        k: (str(v.dtype), tuple(v.shape), int(v.nbytes),
+                            tile_checksums_device(v))
+                        for k, v in dev.items()}
+                else:
+                    dev_sums = {
+                        k: (str(v.dtype), tuple(v.shape),
+                            checksum_words_device(v))
+                        for k, v in dev.items()}
         if async_:
             fut = self._writer_pool().submit(
                 self._write_guarded, step, snap, dev_sums, dev_tiles,
@@ -384,46 +396,59 @@ class FileCheckpointer:
         """Shared sync/async write body: fold device digests, decide
         full-vs-delta, then either gather dirty tiles (transfer O(dirt))
         or drain the full snapshot (base / degraded / CPU fallback)."""
+        with span("ckpt.write", step=step):
+            self._write_job(step, snap, dev_sums, dev_tiles, extra)
+
+    def _write_job(self, step, snap, dev_sums, dev_tiles, extra):
         d2h = [0]
         if dev_tiles is not None:
             from repro.kernels.checksum.ref import scalar_from_tiles
             tiles: Dict[str, serde.LeafTiles] = {}
-            for k, (dt, sh, nb, t) in dev_tiles.items():
-                rows = np.zeros((0, 3), np.uint32) if t is None \
-                    else np.asarray(t)
-                tiles[k] = serde.LeafTiles(nb, dt, sh, rows)
-                d2h[0] += rows.nbytes            # 12 B/tile digest rows
-            for k, v in snap.items():            # host / exotic leaves
-                if k not in tiles:
-                    a = np.asarray(v)
-                    if isinstance(v, jax.Array):
-                        d2h[0] += a.nbytes
-                    tiles[k] = serde._leaf_tiles(a)
-            digests = {k: digest_from_checksum(
-                t.dtype, t.shape, *scalar_from_tiles(t.rows))
-                for k, t in tiles.items()}
+            with span("ckpt.digest_fold", step=step):
+                for k, (dt, sh, nb, t) in dev_tiles.items():
+                    rows = np.zeros((0, 3), np.uint32) if t is None \
+                        else np.asarray(t)
+                    tiles[k] = serde.LeafTiles(nb, dt, sh, rows)
+                    d2h[0] += rows.nbytes            # 12 B/tile digest rows
+                for k, v in snap.items():            # host / exotic leaves
+                    if k not in tiles:
+                        a = np.asarray(v)
+                        if isinstance(v, jax.Array):
+                            d2h[0] += a.nbytes
+                        tiles[k] = serde._leaf_tiles(a)
+                digests = {k: digest_from_checksum(
+                    t.dtype, t.shape, *scalar_from_tiles(t.rows))
+                    for k, t in tiles.items()}
             kind, plan, tiles, base_step = self._chain.decide(
                 snap, step, tiles)
             if kind == "delta" and self._gather_on:
-                gathered = self._gather(snap, plan, d2h)
+                with span("ckpt.d2h", step=step) as sp:
+                    gathered = self._gather(snap, plan, d2h)
+                    sp.set(bytes=d2h[0])
                 meta = {k: _LeafMeta(t.shape, t.dtype)
                         for k, t in tiles.items()}
                 self._write(step, meta, digests, extra, tiles=tiles,
                             decision=(kind, plan, base_step),
                             gathered=gathered, d2h_bytes=d2h[0])
                 return
-            flat = self._drain(snap, d2h)
+            with span("ckpt.d2h", step=step) as sp:
+                flat = self._drain(snap, d2h)
+                sp.set(bytes=d2h[0])
             self._write(step, flat, digests, extra, tiles=tiles,
                         decision=(kind, plan, base_step),
                         d2h_bytes=d2h[0])
             return
-        flat = self._drain(snap, d2h)
+        with span("ckpt.d2h", step=step) as sp:
+            flat = self._drain(snap, d2h)
+            sp.set(bytes=d2h[0])
         digests = None
         if dev_sums is not None:
             digests = {}
-            for k, (dt, sh, s) in dev_sums.items():
-                s0, s1 = (0, 0) if s is None else (int(s[0]), int(s[1]))
-                digests[k] = digest_from_checksum(dt, sh, s0, s1)
+            with span("ckpt.digest_fold", step=step):
+                for k, (dt, sh, s) in dev_sums.items():
+                    s0, s1 = (0, 0) if s is None else (int(s[0]),
+                                                       int(s[1]))
+                    digests[k] = digest_from_checksum(dt, sh, s0, s1)
         self._write(step, flat, digests, extra, d2h_bytes=d2h[0])
 
     def _gather(self, snap, plan: serde.DeltaPlan,
@@ -536,18 +561,20 @@ class FileCheckpointer:
                 def one_shard(i: int) -> Dict[str, str]:
                     part_keys = [k for k in keys if shard_of[k] == i]
                     p = os.path.join(tmp, f"shard_{i:05d}.bin")
-                    if kind == "delta" and gathered is not None:
-                        nbytes[i] = serde.write_delta_file_gathered(
-                            p, {k: gathered[k] for k in part_keys
-                                if k in gathered},
-                            base_step=base_step)
-                    elif kind == "delta":
-                        nbytes[i] = serde.write_delta_file(
-                            p, {k: flat[k] for k in part_keys}, plan,
-                            base_step=base_step)
-                    else:
-                        nbytes[i] = serde.write_file(
-                            p, {k: flat[k] for k in part_keys})
+                    with span("ckpt.shard", step=step, shard=i) as sp:
+                        if kind == "delta" and gathered is not None:
+                            nbytes[i] = serde.write_delta_file_gathered(
+                                p, {k: gathered[k] for k in part_keys
+                                    if k in gathered},
+                                base_step=base_step)
+                        elif kind == "delta":
+                            nbytes[i] = serde.write_delta_file(
+                                p, {k: flat[k] for k in part_keys}, plan,
+                                base_step=base_step)
+                        else:
+                            nbytes[i] = serde.write_file(
+                                p, {k: flat[k] for k in part_keys})
+                        sp.set(bytes=nbytes[i])
                     # crash-injection point: this shard's bytes are down,
                     # the checkpoint is not yet COMMITTED
                     hooks.fire("ckpt.file.shard", step=step, shard=i)
@@ -558,32 +585,35 @@ class FileCheckpointer:
                             for k in part_keys}
 
                 shard_digests: Dict[str, str] = {}
-                for d in pool.map(one_shard, range(self.n_shards)):
-                    shard_digests.update(d)
+                with span("ckpt.shards", step=step):
+                    for d in pool.map(one_shard, range(self.n_shards)):
+                        shard_digests.update(d)
                 man = Manifest.build(step, flat, lambda k: shard_of[k],
                                      self.n_shards, extra,
                                      digests=shard_digests,
                                      kind=kind, base_step=base_step)
-            with open(os.path.join(tmp, "manifest.json"), "w") as f:
-                f.write(man.to_json())
-            # crash-injection point: shards + manifest written, COMMITTED
-            # absent — a kill here must leave this step invisible and the
-            # orphaned tmp dir reapable by the next writer's GC
-            hooks.fire("ckpt.file.pre_commit", step=step)
-            with open(os.path.join(tmp, "COMMITTED"), "w") as f:
-                f.write("ok")
-            final = self._step_dir(step)
-            if os.path.exists(final):
-                shutil.rmtree(final)
-            os.rename(tmp, final)
+            with span("ckpt.commit", step=step):
+                with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                    f.write(man.to_json())
+                # crash-injection point: shards + manifest written,
+                # COMMITTED absent — a kill here must leave this step
+                # invisible and the orphaned tmp dir reapable by the next
+                # writer's GC
+                hooks.fire("ckpt.file.pre_commit", step=step)
+                with open(os.path.join(tmp, "COMMITTED"), "w") as f:
+                    f.write("ok")
+                final = self._step_dir(step)
+                if os.path.exists(final):
+                    shutil.rmtree(final)
+                os.rename(tmp, final)
+                if self._delta_on:
+                    self._chain.commit(step, tiles, kind)
+                self.last_write = {"kind": kind, "bytes": sum(nbytes),
+                                   "d2h_bytes": d2h_bytes}
+                self._gc()
         finally:
             with self._lock:
                 self._live_tmps.discard(tmp_name)
-        if self._delta_on:
-            self._chain.commit(step, tiles, kind)
-        self.last_write = {"kind": kind, "bytes": sum(nbytes),
-                           "d2h_bytes": d2h_bytes}
-        self._gc()
         self._maybe_rebase(step, kind)
 
     # ------------------------------------------------------------ rebase

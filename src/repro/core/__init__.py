@@ -8,6 +8,7 @@ Layers:
   reinit     reinit_main() rollback-point API (the MPI_Reinit analogue)
   elastic    spare pool, mesh epochs, shrinking-recovery option
   recovery   CR / Reinit++ / ULFM strategy objects
+  spans      named host spans on the JAX profiler's clock
 """
 from .events import (FailureEvent, FailureType, GrowCommand, PromoteCommand,
                      Promotion, RankState, RecoveryReport, ReinitCommand,

@@ -25,14 +25,22 @@ from .ref import (MIX_MULS, TILE_WORDS, checksum_words_ref,
 _PALLAS_MIN_WORDS = 1 << 15
 
 
-def _pallas_path(words: jax.Array) -> bool:
+def _pallas_path(n_words: int, sharding) -> bool:
     """The Pallas kernels run on a TPU, for streams long enough to pay
     for a launch, held by one device: a Mosaic kernel cannot be
     partitioned over a mesh, so a sharded or replicated stream takes the
     jnp reduction, which XLA partitions."""
     return (jax.default_backend() == "tpu"
-            and words.size >= _PALLAS_MIN_WORDS
-            and len(words.sharding.device_set) == 1)
+            and n_words >= _PALLAS_MIN_WORDS
+            and len(sharding.device_set) == 1)
+
+
+def digest_bytes(x: jax.Array) -> tuple[int, int]:
+    """(bytes of `x`'s word stream, the part of them that the device
+    digests hand to a Pallas kernel), from the shape alone: the stream
+    is zero-padded to whole words."""
+    nbytes = 4 * -(-x.size * x.dtype.itemsize // 4)
+    return nbytes, nbytes if _pallas_path(nbytes // 4, x.sharding) else 0
 
 
 def device_digestible(x) -> bool:
@@ -87,7 +95,7 @@ def checksum_words(x, *, interpret: bool = False) -> tuple[int, int]:
     words = _device_words(jnp.asarray(x))
     if words.size == 0:
         return 0, 0
-    if interpret or _pallas_path(words):
+    if interpret or _pallas_path(words.size, words.sharding):
         # lazy: host-only digest paths never pay the pallas import
         from .kernel import checksum_kernel
         s0, s1 = checksum_kernel(words, interpret=interpret)
@@ -104,7 +112,7 @@ def checksum_words_device(x: jax.Array):
     words = _device_words(jnp.asarray(x))
     if words.size == 0:
         return None
-    if _pallas_path(words):
+    if _pallas_path(words.size, words.sharding):
         from .kernel import checksum_kernel
         return checksum_kernel(words)
     return _wordsum_jnp(words)
@@ -135,7 +143,7 @@ def tile_checksums_device(x, *, interpret: bool = False):
     words = _device_words(jnp.asarray(x))
     if words.size == 0:
         return None
-    if interpret or _pallas_path(words):
+    if interpret or _pallas_path(words.size, words.sharding):
         from .kernel import tile_checksum_kernel
         return tile_checksum_kernel(words, interpret=interpret)
     return _tilesum_jnp(words)
@@ -166,7 +174,7 @@ def gather_tiles_device(x, idx, *, interpret: bool = False) -> jax.Array:
     """
     tiles2d = _device_tiles2d(x)
     idx = jnp.asarray(np.asarray(idx, np.int32))
-    if interpret or _pallas_path(tiles2d):
+    if interpret or _pallas_path(tiles2d.size, tiles2d.sharding):
         from .kernel import gather_tiles_kernel
         return gather_tiles_kernel(
             tiles2d.reshape(-1, 128), idx, interpret=interpret)

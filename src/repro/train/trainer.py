@@ -22,7 +22,6 @@ state of an uninterrupted run — the integration tests assert exactly that.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Optional
 
 import jax
@@ -36,6 +35,7 @@ from repro.core import (ClusterView, ElasticManager, FailureEvent,
                         RecoveryReport, ROLLBACK, RollbackSignal,
                         apply_recovery, get_strategy, reinit_main,
                         root_handle_failure)
+from repro.core.spans import span, step_span
 from repro.models.model import Model
 from repro.scenarios.schema import GRAY_DRAIN_PERSIST, GRAY_HOWS, \
     gray_delay_s
@@ -226,11 +226,12 @@ class Trainer:
             self._handle_failure(failure)
             raise RollbackSignal(self.view.epoch)
         state = self.state
-        if self.mesh is not None and self.mesh.shape.get("data", 1) > 1:
-            buddy = buddy_exchange(state, self.mesh, self.rules)
-        else:
-            buddy = jax.tree.map(lambda a: a + 0, state)   # device copy
-        local = jax.tree.map(lambda a: a + 0, state)
+        with span("save.copies"):
+            if self.mesh is not None and self.mesh.shape.get("data", 1) > 1:
+                buddy = buddy_exchange(state, self.mesh, self.rules)
+            else:
+                buddy = jax.tree.map(lambda a: a + 0, state)  # device copy
+            local = jax.tree.map(lambda a: a + 0, state)
         self.file_ckpt.save(step, state, async_=self.policy.async_file)
         failure = self._injected_at("worker.ckpt.pre_push", step)
         if failure is not None:
@@ -257,13 +258,13 @@ class Trainer:
             return self._handle_failure_shrink(rep, failure)
 
         # --- detection (child monitor / channel break at the root)
-        t0 = time.monotonic()
-        cmd = root_handle_failure(self.view, failure)
-        states = apply_recovery(self.view, cmd)
-        assert len(states) == self.n_ranks      # non-shrinking invariant
-        if self.elastic is not None:
-            self.elastic.nonshrink_plan(failure)     # mesh bookkeeping
-        rep.detect_s = time.monotonic() - t0
+        with span("recovery.detect") as sp:
+            cmd = root_handle_failure(self.view, failure)
+            states = apply_recovery(self.view, cmd)
+            assert len(states) == self.n_ranks  # non-shrinking invariant
+            if self.elastic is not None:
+                self.elastic.nonshrink_plan(failure)     # mesh bookkeeping
+        rep.detect_s = sp.seconds
 
         # --- zero-rollback fast path (replica): the victim's warm shadow
         # holds the state at the failure step — promotion replaces the
@@ -273,42 +274,44 @@ class Trainer:
         # cold shadow (nothing mirrored yet, or consumed by the recovery
         # in flight) falls through to the ordinary path below.
         if self.strategy.replicates and self.shadow_ckpt is not None:
-            t0 = time.monotonic()
-            step, shadow = self.shadow_ckpt
-            self.shadow_ckpt = None   # consumed: a cascade during this
-                                      # recovery has no second standby
-            if failure.kind is FailureType.NODE:
-                self.mem_ckpt = None  # buddy copies died with the node
-            rep.mpi_recovery_s = time.monotonic() - t0
-            t0 = time.monotonic()
-            self.state = jax.tree.map(lambda a: a + 0, shadow)
-            rep.ckpt_read_s = time.monotonic() - t0
+            with span("recovery.mpi") as sp:
+                step, shadow = self.shadow_ckpt
+                self.shadow_ckpt = None   # consumed: a cascade during this
+                                          # recovery has no second standby
+                if failure.kind is FailureType.NODE:
+                    self.mem_ckpt = None  # buddy copies died with the node
+            rep.mpi_recovery_s = sp.seconds
+            with span("recovery.restore") as sp:
+                self.state = jax.tree.map(lambda a: a + 0, shadow)
+                jax.block_until_ready(self.state)
+            rep.ckpt_read_s = sp.seconds
             rep.rollback_step = step
             self.reports.append(rep)
             self._fire_cascades()
             return rep
 
         # --- MPI recovery: what each strategy actually does
-        t0 = time.monotonic()
         ckpt_kind = self.strategy.checkpoint_kind(failure.kind)
-        if self.strategy.redeploys:
-            # CR: teardown — lose device state AND compiled artifacts
-            self.state = None
-            self.mem_ckpt = None
-            self._jitted = None
-            self._build_step()
-            jax.clear_caches()
-        else:
-            if self.strategy.allrank_collectives:
-                # ULFM: revoke/shrink/agree rounds across all ranks
-                x = jnp.ones((self.n_ranks,), jnp.float32)
-                for _ in range(self.strategy.allrank_collectives):
-                    x = jax.jit(lambda v: v / jnp.sum(v))(x)
-                x.block_until_ready()
-            if failure.kind is FailureType.NODE:
-                # node loss invalidates buddy copies of that node's shards
+        with span("recovery.mpi") as sp:
+            if self.strategy.redeploys:
+                # CR: teardown — lose device state AND compiled artifacts
+                self.state = None
                 self.mem_ckpt = None
-        rep.mpi_recovery_s = time.monotonic() - t0
+                self._jitted = None
+                self._build_step()
+                jax.clear_caches()
+            else:
+                if self.strategy.allrank_collectives:
+                    # ULFM: revoke/shrink/agree rounds across all ranks
+                    x = jnp.ones((self.n_ranks,), jnp.float32)
+                    for _ in range(self.strategy.allrank_collectives):
+                        x = jax.jit(lambda v: v / jnp.sum(v))(x)
+                    x.block_until_ready()
+                if failure.kind is FailureType.NODE:
+                    # node loss invalidates buddy copies of that node's
+                    # shards
+                    self.mem_ckpt = None
+        rep.mpi_recovery_s = sp.seconds
 
         # --- application recovery: reload the appropriate checkpoint.
         # The memory tier is only taken when it is at least as new as the
@@ -316,37 +319,45 @@ class Trainer:
         # push (worker.ckpt.pre_push) leaves the file one step ahead, and
         # the merged restore must reach it (the real runtime's merged
         # buddy+file restore maps, in-process)
-        t0 = time.monotonic()
-        use_memory = ckpt_kind == "memory" and self.mem_ckpt is not None
-        if use_memory:
-            self.file_ckpt.wait()
-            fsteps = self.file_ckpt.steps()
-            if fsteps and fsteps[-1] > self.mem_ckpt[0]:
-                use_memory = False
-        if use_memory:
-            step, local, buddy = self.mem_ckpt
-            if self.mesh is not None and self.mesh.shape.get("data", 1) > 1:
-                restored = restore_from_buddy(buddy, self.mesh, self.rules)
-            else:
-                restored = buddy
-            # survivors keep `local`; the failed shard comes from `restored`
-            # (same global value — asserted in tests via digest equality)
-            self.state = jax.tree.map(lambda a: a + 0, restored)
-            rollback_step = step
-        else:
-            self.file_ckpt.wait()
-            step, state = self.file_ckpt.load_latest()
-            if step is None:
-                self.state = self.init_state()
-                rollback_step = 0
-            else:
-                self.state = jax.tree.map(jnp.asarray, state)
+        with span("recovery.restore") as sp:
+            use_memory = ckpt_kind == "memory" and self.mem_ckpt is not None
+            if use_memory:
+                self.file_ckpt.wait()
+                fsteps = self.file_ckpt.steps()
+                if fsteps and fsteps[-1] > self.mem_ckpt[0]:
+                    use_memory = False
+            if use_memory:
+                step, local, buddy = self.mem_ckpt
+                if self.mesh is not None \
+                        and self.mesh.shape.get("data", 1) > 1:
+                    restored = restore_from_buddy(buddy, self.mesh,
+                                                  self.rules)
+                else:
+                    restored = buddy
+                # survivors keep `local`; the failed shard comes from
+                # `restored` (same global value — asserted in tests via
+                # digest equality)
+                self.state = jax.tree.map(lambda a: a + 0, restored)
                 rollback_step = step
-        rep.ckpt_read_s = time.monotonic() - t0
+            else:
+                rollback_step = self._restore_file()
+            jax.block_until_ready(self.state)
+        rep.ckpt_read_s = sp.seconds
         rep.rollback_step = rollback_step
         self.reports.append(rep)
         self._fire_cascades()
         return rep
+
+    def _restore_file(self) -> int:
+        """Reload the newest committed file checkpoint, or a fresh state
+        when there is none; returns the step rolled back to."""
+        self.file_ckpt.wait()
+        step, state = self.file_ckpt.load_latest()
+        if step is None:
+            self.state = self.init_state()
+            return 0
+        self.state = jax.tree.map(jnp.asarray, state)
+        return step
 
     def _fire_cascades(self):
         """Cascade injection points (a second failure during the recovery
@@ -372,36 +383,30 @@ class Trainer:
         survivors — the step-indexed TokenPipeline keeps the *global*
         batch, so the run stays on the same data trajectory through the
         shrink."""
-        t0 = time.monotonic()
-        cmd = self.elastic.shrink(failure)   # view+mesh+dropped ledger
-        self.n_ranks = len(cmd.world)
-        rep.detect_s = time.monotonic() - t0
+        with span("recovery.detect") as sp:
+            cmd = self.elastic.shrink(failure)   # view+mesh+dropped ledger
+            self.n_ranks = len(cmd.world)
+        rep.detect_s = sp.seconds
 
-        t0 = time.monotonic()
-        self._build_step()           # mesh epoch bumped: re-lower the step
-        if failure.kind is FailureType.NODE:
-            self.mem_ckpt = None     # the lost node took its buddy-held
-                                     # copies with it
-        rep.mpi_recovery_s = time.monotonic() - t0
+        with span("recovery.mpi") as sp:
+            self._build_step()       # mesh epoch bumped: re-lower the step
+            if failure.kind is FailureType.NODE:
+                self.mem_ckpt = None     # the lost node took its
+                                         # buddy-held copies with it
+        rep.mpi_recovery_s = sp.seconds
 
         # survivors roll back to their newest durable state: the buddy
         # memory copy when it survived (process shrink), else the file
         # checkpoint at the cut
-        t0 = time.monotonic()
-        if self.mem_ckpt is not None:
-            step, local, _ = self.mem_ckpt
-            self.state = jax.tree.map(lambda a: a + 0, local)
-            rollback_step = step
-        else:
-            self.file_ckpt.wait()
-            step, state = self.file_ckpt.load_latest()
-            if step is None:
-                self.state = self.init_state()
-                rollback_step = 0
-            else:
-                self.state = jax.tree.map(jnp.asarray, state)
+        with span("recovery.restore") as sp:
+            if self.mem_ckpt is not None:
+                step, local, _ = self.mem_ckpt
+                self.state = jax.tree.map(lambda a: a + 0, local)
                 rollback_step = step
-        rep.ckpt_read_s = time.monotonic() - t0
+            else:
+                rollback_step = self._restore_file()
+            jax.block_until_ready(self.state)
+        rep.ckpt_read_s = sp.seconds
         rep.rollback_step = rollback_step
         rep.world_after = self.n_ranks
         self.reports.append(rep)
@@ -484,25 +489,26 @@ class Trainer:
             strategy=self.strategy.name,
             failure=FailureEvent(kind=FailureType.NODE, node=node,
                                  at_step=repair.step))
-        t0 = time.monotonic()
-        cmd = self.elastic.grow(node)
-        self.n_ranks = len(cmd.world)
-        rep.detect_s = time.monotonic() - t0
+        with span("recovery.detect") as sp:
+            cmd = self.elastic.grow(node)
+            self.n_ranks = len(cmd.world)
+        rep.detect_s = sp.seconds
 
-        t0 = time.monotonic()
-        self._build_step()           # mesh epoch bumped: re-lower the
+        with span("recovery.mpi") as sp:
+            self._build_step()       # mesh epoch bumped: re-lower the
                                      # step for the re-expanded world
-        rep.mpi_recovery_s = time.monotonic() - t0
+        rep.mpi_recovery_s = sp.seconds
 
         # the re-admitted ranks restore from the durable checkpoint at
         # the consistent cut (Table-2 "grow" scheme: file tier)
-        t0 = time.monotonic()
-        self.file_ckpt.wait()
-        step, state = self.file_ckpt.load_latest()
-        if step is not None:
-            self.state = jax.tree.map(jnp.asarray, state)
-            rep.rollback_step = step
-        rep.ckpt_read_s = time.monotonic() - t0
+        with span("recovery.restore") as sp:
+            self.file_ckpt.wait()
+            step, state = self.file_ckpt.load_latest()
+            if step is not None:
+                self.state = jax.tree.map(jnp.asarray, state)
+                rep.rollback_step = step
+            jax.block_until_ready(self.state)
+        rep.ckpt_read_s = sp.seconds
         rep.world_after = self.n_ranks
         self.reports.append(rep)
         self._fire_cascades()
@@ -523,24 +529,39 @@ class Trainer:
 
         step = int(self.state["step"])
         while step < tc.total_steps:
-            ROLLBACK.check()                      # safe-point (paper §3.2)
-            failure = self.injector.check(step, self.view) \
-                if self.injector else None
-            if failure is not None:
-                self._handle_failure(failure)
-                raise RollbackSignal(self.view.epoch)
-            repair = self.injector.check_repair(step) \
-                if self.injector is not None \
-                and hasattr(self.injector, "check_repair") else None
-            if repair is not None and self._handle_repair(repair):
-                raise RollbackSignal(self.view.epoch)
+            # one span an iteration; its children tile it, so the device's
+            # idle between steps falls in a named part of the host's work
+            with step_span(step):
+                step = self._iteration(step, hb)
+        with span("train.drain"):
+            self.file_ckpt.wait()
+        return step
 
-            t0 = time.monotonic()
+    def _iteration(self, step: int, hb: float) -> int:
+        """One step of the loop from `step`; returns the step reached."""
+        ROLLBACK.check()                          # safe-point (paper §3.2)
+        failure = self.injector.check(step, self.view) \
+            if self.injector else None
+        if failure is not None:
+            self._handle_failure(failure)
+            raise RollbackSignal(self.view.epoch)
+        repair = self.injector.check_repair(step) \
+            if self.injector is not None \
+            and hasattr(self.injector, "check_repair") else None
+        if repair is not None and self._handle_repair(repair):
+            raise RollbackSignal(self.view.epoch)
+
+        with span("train.feed") as feed:
             batch = self.data.batch(step)
+        with span("train.dispatch") as dispatch:
             self.state, (loss, _) = self._step(self.state, batch)
+        with span("train.wait") as wait:
             jax.block_until_ready(self.state["params"])
-            dt = time.monotonic() - t0
+        dt = feed.seconds + dispatch.seconds + wait.seconds
+        with span("train.readback"):
             step = int(self.state["step"])
+            loss = float(loss)
+        with span("train.bookkeeping"):
             self.straggler.observe(step, dt)
             drain = self._observe_gray(step, dt)
             if drain is not None:
@@ -556,14 +577,14 @@ class Trainer:
                 # promote zero-rollback
                 self.shadow_ckpt = (step, jax.tree.map(lambda a: a + 0,
                                                        self.state))
-            self.logs.append(StepLog(step=step, loss=float(loss),
-                                     seconds=dt, heartbeat_overhead=hb))
-            if self.policy.should_checkpoint(step):
-                self._save_ckpt(step)
-            if tc.log_every and step % tc.log_every == 0:
+            self.logs.append(StepLog(step=step, loss=loss, seconds=dt,
+                                     heartbeat_overhead=hb))
+            if self.tc.log_every and step % self.tc.log_every == 0:
                 print(f"[{self.strategy.name}] step {step} "
-                      f"loss {float(loss):.4f} ({dt*1e3:.1f} ms)")
-        self.file_ckpt.wait()
+                      f"loss {loss:.4f} ({dt*1e3:.1f} ms)")
+        if self.policy.should_checkpoint(step):
+            with span("train.save", step=step):
+                self._save_ckpt(step)
         return step
 
     def run(self) -> dict:
