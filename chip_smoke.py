@@ -51,9 +51,18 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 SRC = os.path.join(ROOT, "src")
 WORK = os.path.join(ROOT, ".chip_smoke")      # checkpoints of a run
 
-# the checkpoint path's Pallas kernels, in repro.kernels.checksum.kernel
-PALLAS_KERNELS = ("checksum_kernel", "tile_checksum_kernel",
-                  "gather_tiles_kernel")
+# the Pallas kernels of the chip path, by the module that holds them: the
+# checkpoint path's digests and gather, and the model step's flash
+# attention (forward, and the backward's dK/dV and dQ kernels)
+PALLAS_MODULES = {
+    "repro.kernels.checksum.kernel": ("checksum_kernel",
+                                      "tile_checksum_kernel",
+                                      "gather_tiles_kernel"),
+    "repro.kernels.flash_attention.kernel": ("flash_fwd", "flash_bwd_dkv",
+                                             "flash_bwd_dq"),
+}
+PALLAS_KERNELS = tuple(n for names in PALLAS_MODULES.values()
+                       for n in names)
 # (run name, recovery strategy, failure kind or None)
 TRAIN_RUNS = (("fault-free", "reinit", None),
               ("reinit-process", "reinit", "process"),
@@ -101,26 +110,32 @@ def compile_seconds():
 
 @contextlib.contextmanager
 def count_pallas_calls():
-    """Count the dispatches of the checkpoint path's Pallas kernels.
-    `kernels.checksum.ops` imports each kernel from its module at the
-    call, so wrapping the module attributes sees every dispatch."""
-    from repro.kernels.checksum import kernel as kmod
+    """Count the calls of the chip path's Pallas kernels. Each is looked
+    up in its module at the call (`kernels.checksum.ops` imports the
+    digest kernels there; the flash custom VJP calls its kernels as module
+    globals), so wrapping the module attributes sees every call. A digest
+    counts once a dispatch; a flash kernel once each time a step program
+    that holds it is traced."""
+    import importlib
     counts = dict.fromkeys(PALLAS_KERNELS, 0)
-    orig = {n: getattr(kmod, n) for n in PALLAS_KERNELS}
+    orig = {}
 
-    def counted(name):
+    def counted(name, fn):
         def call(*args, **kwargs):
             counts[name] += 1
-            return orig[name](*args, **kwargs)
+            return fn(*args, **kwargs)
         return call
 
-    for n in PALLAS_KERNELS:
-        setattr(kmod, n, counted(n))
+    for modname, names in PALLAS_MODULES.items():
+        mod = importlib.import_module(modname)
+        for n in names:
+            orig[mod, n] = getattr(mod, n)
+            setattr(mod, n, counted(n, orig[mod, n]))
     try:
         yield counts
     finally:
-        for n, f in orig.items():
-            setattr(kmod, n, f)
+        for (mod, n), f in orig.items():
+            setattr(mod, n, f)
 
 
 def _frame_kinds(ckpt_dir: str) -> dict:
@@ -365,8 +380,10 @@ def _one_chip(seed: int, workdir: str):
     for line in out["runs"]:
         emit("train", **line)
     emit("train-done", pallas=out["pallas"], peak_bytes_in_use=peak_bytes())
+    # at these shapes the dK/dV kernel accumulates dQ as well, so the
+    # separate dQ kernel has no call
     for k, n in out["pallas"].items():
-        check(n > 0, f"Pallas {k} never ran")
+        check(n > 0 or k == "flash_bwd_dq", f"Pallas {k} never ran")
 
     emit("serve-config", arch=serve_cfg.name, n_layers=serve_cfg.n_layers,
          published_layers=published.n_layers,
@@ -388,7 +405,8 @@ def _four_chips(seed: int, workdir: str):
     for line in out["runs"]:
         emit("train-4chip", mesh=dict(mesh.shape), **line)
     # a Mosaic kernel cannot be partitioned over the mesh, so the digests
-    # of the sharded state take the jnp path: the counts stay 0 here
+    # of the sharded state and the attention of the sharded step take
+    # their jnp paths: the counts stay 0 here
     emit("train-4chip-done", pallas=out["pallas"],
          peak_bytes_in_use=peak_bytes())
 

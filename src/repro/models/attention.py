@@ -1,10 +1,14 @@
 """Attention: MHA/GQA/MQA with qk-norm, QKV bias, RoPE, KV-cache decode.
 
-Three interchangeable inner implementations (same math):
+Interchangeable inner implementations (same math):
   - "naive":   materializes (B,H,S,S) scores — reference / tiny tests only.
   - "chunked": flash-style streaming over KV blocks in pure jnp — bounded
-               memory, used for CPU dry-runs and as the oracle-scale impl.
-  - "pallas":  the TPU Pallas flash kernel (repro.kernels.flash_attention).
+               memory. The default: `inner_attention` runs it as the
+               Pallas flash kernel (repro.kernels.flash_attention, with
+               its own backward) wherever `flash_blocks` says the kernel
+               applies — on a TPU, for shapes it tiles, off a multi-device
+               mesh — and as the jnp scan everywhere else (the CPU).
+  - "pallas":  the Pallas flash kernel, whatever the platform.
 """
 from __future__ import annotations
 
@@ -13,7 +17,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.partition import shard_constraint
+from repro.sharding.partition import active_mesh, shard_constraint
 
 from .config import ModelConfig
 from .layers import _init, apply_rope, rmsnorm, rmsnorm_init
@@ -150,6 +154,58 @@ def chunked_attention(q, k, v, *, causal: bool, q_offset=0,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
+FLASH_HEAD_DIMS = (64, 128)      # head widths the flash kernel is built for
+FLASH_MIN_BLOCK = 128            # a block spans whole (8, 128) tiles
+FLASH_BLOCK = 512                # the largest block; see flash_blocks
+
+
+def _flash_block(s: int) -> int:
+    b = FLASH_BLOCK
+    while s % b:
+        b //= 2
+    return b
+
+
+def flash_blocks(q_shape, k_shape, *, platform: Optional[str] = None,
+                 mesh=None) -> Optional[tuple[int, int]]:
+    """(block_q, block_k) of the Pallas flash kernel for these shapes, or
+    None where the jnp scan runs instead: off a TPU; on a multi-device
+    mesh, since a Mosaic kernel is not partitioned; for a head width the
+    kernel is not built for; where Sq or Sk does not tile by 128.
+
+    q_shape (B, Sq, H, hd), k_shape (B, Sk, Hkv, hd). `platform` and `mesh`
+    default to what the traced code runs on: `jax.default_backend()` and
+    the constraint scope's mesh, which the trainer arms for a sharded step.
+    """
+    platform = platform or jax.default_backend()
+    mesh = active_mesh() if mesh is None else mesh
+    if platform != "tpu" or (mesh is not None and mesh.size > 1):
+        return None
+    Sq, hd, Sk = q_shape[1], q_shape[3], k_shape[1]
+    if hd not in FLASH_HEAD_DIMS or Sq % FLASH_MIN_BLOCK \
+            or Sk % FLASH_MIN_BLOCK:
+        return None
+    return _flash_block(Sq), _flash_block(Sk)
+
+
+def inner_attention(q, k, v, *, causal: bool,
+                    impl: str = "chunked") -> jnp.ndarray:
+    """The attention core, q (B,Sq,H,hd), k/v (B,Sk,Hkv,hd) -> (B,Sq,H,hd).
+    "chunked" takes the flash kernel where `flash_blocks` allows it and
+    the jnp scan elsewhere."""
+    if impl == "naive":
+        return naive_attention(q, k, v, causal=causal)
+    if impl not in ("chunked", "pallas"):
+        raise ValueError(impl)
+    blocks = flash_blocks(q.shape, k.shape) if impl == "chunked" \
+        else (FLASH_MIN_BLOCK, FLASH_MIN_BLOCK)
+    if blocks is None:
+        return chunked_attention(q, k, v, causal=causal)
+    from repro.kernels.flash_attention import ops as fa_ops
+    return fa_ops.flash_attention(q, k, v, causal=causal, block_q=blocks[0],
+                                  block_k=blocks[1])
+
+
 def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
               positions: Optional[jnp.ndarray] = None,
               causal: bool = True,
@@ -170,15 +226,7 @@ def attention(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         causal = False
     else:
         q, k, v = _project_qkv(p, x, cfg, positions, compute_dtype)
-    if impl == "naive":
-        o = naive_attention(q, k, v, causal=causal)
-    elif impl == "chunked":
-        o = chunked_attention(q, k, v, causal=causal)
-    elif impl == "pallas":
-        from repro.kernels.flash_attention import ops as fa_ops
-        o = fa_ops.flash_attention(q, k, v, causal=causal)
-    else:
-        raise ValueError(impl)
+    o = inner_attention(q, k, v, causal=causal, impl=impl)
     o = o.reshape(B, S, cfg.n_heads * cfg.head_dim)
     o = shard_constraint(o, "batch", None, "heads")
     return o @ p["wo"].astype(compute_dtype)
@@ -192,13 +240,7 @@ def attention_with_kv(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     if positions is None:
         positions = jnp.arange(S)[None, :]
     q, k, v = _project_qkv(p, x, cfg, positions, compute_dtype)
-    if impl == "naive":
-        o = naive_attention(q, k, v, causal=True)
-    elif impl == "pallas":
-        from repro.kernels.flash_attention import ops as fa_ops
-        o = fa_ops.flash_attention(q, k, v, causal=True)
-    else:
-        o = chunked_attention(q, k, v, causal=True)
+    o = inner_attention(q, k, v, causal=True, impl=impl)
     o = o.reshape(B, S, cfg.n_heads * cfg.head_dim)
     return o @ p["wo"].astype(compute_dtype), k, v
 

@@ -28,7 +28,8 @@ Params = Any
 @dataclasses.dataclass(frozen=True)
 class ExecConfig:
     """Execution knobs (hillclimb levers) — static under jit."""
-    attn_impl: str = "chunked"        # naive | chunked | pallas
+    attn_impl: str = "chunked"        # naive | chunked | pallas (see
+                                      # attention.inner_attention)
     remat_policy: str = "full"        # none | full | dots
     xent_chunks: int = 4
     scan_layers: bool = True

@@ -29,6 +29,12 @@ def constraint_scope(mesh: Mesh, rules: ShardingRules):
         _CTX.reset(tok)
 
 
+def active_mesh() -> Optional[Mesh]:
+    """The mesh of the current constraint scope, None outside one."""
+    ctx = _CTX.get()
+    return None if ctx is None else ctx[0]
+
+
 def shard_constraint(x: jnp.ndarray, *logical_axes) -> jnp.ndarray:
     """with_sharding_constraint by logical axes; identity outside a scope."""
     ctx = _CTX.get()

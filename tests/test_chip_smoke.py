@@ -38,6 +38,25 @@ def test_train_phase_bit_identical(smoke, tmp_path):
     assert not any(out["pallas"].values())
 
 
+def test_pallas_counter_sees_the_flash_kernels(smoke):
+    """The counter wraps the flash custom VJP's kernels too: a gradient
+    through the kernel (interpret mode, as the CPU runs it) counts the
+    forward and both backward kernels once each."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.flash_attention.ops import flash_attention
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+    kv = jnp.ones((1, 128, 1, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, interpret=True))
+
+    with smoke.count_pallas_calls() as calls:
+        jax.grad(loss, (0, 1, 2))(q, kv, kv)
+    assert calls["flash_fwd"] == 1 and calls["flash_bwd_dkv"] == 1
+    assert calls["checksum_kernel"] == 0
+
+
 def test_serve_phase_snapshot_restore_bit_identical(smoke):
     line = smoke.serve_phase(reduced(get_config("qwen2-7b")), n_slots=4,
                              max_len=64, n_requests=8, prompt_lens=(8, 16),

@@ -85,6 +85,28 @@ def test_flash_attention_forward_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_flash_attention_backward_compiles(one_chip):
+    """Forward and backward (jax.grad through the custom VJP) at the
+    widths of the train.ckpt cell, qwen2-0.5b-l4: batch 4 x 1024, 14
+    query and 2 KV heads of 64, with the blocks the model path picks."""
+    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.models.attention import flash_blocks
+    q_shape, kv_shape = (4, 1024, 14, 64), (4, 1024, 2, 64)
+    bq, bk = flash_blocks(q_shape, kv_shape, platform="tpu")
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+        return jnp.sum(o.astype(jnp.float32))
+
+    q = _shape(one_chip, q_shape, jnp.bfloat16)
+    kv = _shape(one_chip, kv_shape, jnp.bfloat16)
+    text = _compiled_text(jax.grad(loss, (0, 1, 2)), q, kv, kv)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel in ("flash_fwd", "flash_bwd_dkv"):
+        assert any(kernel in line.split("=")[0] for line in calls), kernel
+
+
 def test_selective_scan_compiles(one_chip):
     from repro.kernels.mamba_scan.kernel import selective_scan
     ds = FALCON_MAMBA.ssm_state
