@@ -37,9 +37,9 @@ import time
 
 import numpy as np
 
-from bench import flops, harness, weights
-from bench.reference import dense
+from bench import harness
 from bench.traffic.tokens import TokenFeed
+from bench.weights import check_layout
 
 WARM_TIMED_STEPS = 8          # steps timed in set-up to size the window
 FIRST_STEPS = 3               # steps the reference follows
@@ -103,13 +103,14 @@ def reference_first_steps(cfg: dict, spec: dict, seed: int,
     first steps, from the same seed's weights and batches (only their
     first `rows` rows when given: the half-batch fault)."""
     import jax
+    arch = harness.arch(cfg)
     with jax.default_matmul_precision("highest"):
-        params = weights.make(cfg, harness.seed_key(seed, 1))
+        params = arch.make_weights(cfg, harness.seed_key(seed, 1))
         feed = _feed(cfg, spec["train"], seed)
         batches = [{k: v[:rows] for k, v in feed.batch(s).items()}
                    for s in range(FIRST_STEPS)]
-        losses, g1, p3 = dense.train(params, batches, cfg, spec["optimizer"],
-                                     prec)
+        losses, g1, p3 = arch.train(params, batches, cfg, spec["optimizer"],
+                                    prec)
         out = {"losses": losses, "grad": _leaf_norms(g1),
                "update": _diff_norms(p3, params)}
         del params, g1, p3
@@ -132,11 +133,12 @@ class TrainCell:
 
         cfg, spec = ctx.config, ctx.workload
         t = spec["train"]
-        model = Model(harness.model_config(cfg))
+        arch = self.arch = harness.arch(cfg)
+        model = Model(arch.model_config(cfg))
         self.feed = _feed(cfg, t, ctx.seed)
         self.opt = AdamWConfig(**spec["optimizer"])
-        params = weights.make(cfg, harness.seed_key(ctx.seed, 1))
-        weights.check_layout(params, model.abstract_params())
+        params = arch.make_weights(cfg, harness.seed_key(ctx.seed, 1))
+        check_layout(params, model.abstract_params())
         self.tc = TrainConfig(
             total_steps=0, ckpt_dir=os.path.join(ctx.work_dir, "ckpt"),
             ckpt_every=NO_SAVE, ckpt_shards=t["ckpt_shards"],
@@ -264,7 +266,8 @@ def run(ctx) -> dict:
            "setup_s": t0 - ctx.t_start}
     records = {
         "steps_run": len(logs),
-        "flops_per_step": flops.train_step(cfg, t["batch"], t["seq"]),
+        "flops_per_step": cell.arch.train_step_flops(cfg, t["batch"],
+                                                     t["seq"]),
         "save_s": [b - a for a, b in spans.between("save", t0, t1)],
         "d2h_bytes": [n for at, n in cell.d2h if t0 <= at <= t1],
         "digested_bytes": sum(n for at, n in cell.digested

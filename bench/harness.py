@@ -67,23 +67,37 @@ def cell_metrics(name: str) -> tuple[list[dict], list[dict]]:
             [m for m in b["per_layer"] if mine(m)])
 
 
-def metric_reader(name: str) -> Callable:
-    """`read(ctx)` of bench/metrics/<name>.py."""
-    path = os.path.join(BENCH, "metrics", f"{name}.py")
+def _module(kind: str, name: str):
+    """bench/<kind>/<name>.py, loaded from its file."""
+    path = os.path.join(BENCH, kind, f"{name}.py")
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
-def driver(name: str):
-    path = os.path.join(BENCH, "drivers", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_driver_{name}",
-                                                  path)
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def metric_reader(name: str) -> Callable:
+    """`read(ctx)` of bench/metrics/<name>.py."""
+    return _module("metrics", name).read
+
+
+def driver(name: str):
+    return _module("drivers", name)
+
+
+def arch(cfg: dict):
+    """The architecture module bench/arch/<name>.py of a configuration,
+    named by its file's "arch" key ("dense" where it has none): the
+    program's ModelConfig, the seeded weights, the plain reference and
+    the FLOP count. An unknown name is an error, never a default."""
+    name = cfg.get("arch", "dense")
+    known = sorted(f[:-len(".py")] for f in os.listdir(
+        os.path.join(BENCH, "arch")) if f.endswith(".py"))
+    if name not in known:
+        raise BenchError(f"{cfg.get('name')}: no architecture module "
+                         f"{name!r}; known: {known}")
+    return _module("arch", name)
 
 
 def peaks(device_kind: str) -> dict:
@@ -110,21 +124,6 @@ def load_repro():
         os.path.abspath(repro.configs.__file__)))
     if where != pkg:
         raise BenchError(f"repro imported from {where}, not {pkg}")
-
-
-def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family=cfg["family"],
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg["head_dim"], qkv_bias=cfg["qkv_bias"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-        param_dtype=cfg["param_dtype"], compute_dtype=cfg["compute_dtype"])
 
 
 # ------------------------------------------------------------------- chips

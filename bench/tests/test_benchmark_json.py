@@ -58,7 +58,10 @@ def test_cells():
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(name) and NAME.match(w["traffic"])
         assert w["chips"] in (1, 4) and _line(w["why"])
-        assert harness.workload(name)["driver"] == "train"
+        driver = harness.workload(name)["driver"]
+        assert NAME.match(driver) and os.path.isfile(os.path.join(
+            harness.BENCH, "drivers", f"{driver}.py"))
+        harness.arch(harness.config(w["config"]))
         e2e, per_layer = harness.cell_metrics(name)
         names = [m["name"] for m in e2e]
         assert "setup_s" in names and len(names) >= 2 and per_layer

@@ -1,11 +1,13 @@
 """A later PR adds a configuration, a cell and a per-layer metric as new
 files and new entries, and the harness finds them with no edit to a file
-that exists."""
+that exists: also a configuration of another architecture, with its own
+architecture module, and a cell of another driver."""
 import hashlib
 import json
 import os
 import shutil
 
+import jax
 import pytest
 
 from bench import harness
@@ -14,6 +16,8 @@ from bench import harness
 def _digests(root):
     out = {}
     for d, _, files in os.walk(root):
+        if "__pycache__" in d:
+            continue
         for f in files:
             p = os.path.join(d, f)
             with open(p, "rb") as fh:
@@ -25,8 +29,9 @@ def _digests(root):
 @pytest.fixture
 def tree(tmp_path, monkeypatch):
     bench = tmp_path / "bench"
-    for sub in ("configs", "workloads", "metrics"):
-        shutil.copytree(os.path.join(harness.BENCH, sub), bench / sub)
+    for sub in ("arch", "configs", "drivers", "workloads", "metrics"):
+        shutil.copytree(os.path.join(harness.BENCH, sub), bench / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(harness.BENCH, "peaks.json"), bench)
     shutil.copy(os.path.join(harness.ROOT, "BENCHMARK.json"), tmp_path)
     monkeypatch.setattr(harness, "ROOT", str(tmp_path))
@@ -80,3 +85,70 @@ def test_a_cell_must_agree_with_its_entry(tree):
         harness.workload(b["workloads"][0]["name"])
     with pytest.raises(harness.BenchError):
         harness.workload("no.such.cell")
+
+
+ARCH = """
+from repro.models.config import ModelConfig
+
+
+def model_config(cfg):
+    return ModelConfig(name=cfg["name"], family="moe", n_layers=2,
+                       d_model=32, n_heads=4, n_kv_heads=2, d_ff=16,
+                       vocab_size=cfg["vocab_size"],
+                       n_experts=cfg["num_experts"],
+                       experts_per_token=cfg["num_experts_per_tok"])
+"""
+
+DRIVER = """
+def run(ctx):
+    return {"e2e": {}, "records": {}, "checks": [], "attempted": 0,
+            "failed": 0, "device": {}, "trace_dir": None}
+"""
+
+
+def test_another_architecture_and_driver_are_found_by_name(tree):
+    before = _digests(tree / "bench")
+    (tree / "bench/arch/tiny_moe.py").write_text(ARCH)
+    (tree / "bench/drivers/probe_serve.py").write_text(DRIVER)
+    cfg = {"name": "tiny-moe-l2", "arch": "tiny_moe", "family": "moe",
+           "vocab_size": 64, "num_experts": 4, "num_experts_per_tok": 2,
+           "reduced": []}
+    (tree / "bench/configs/tiny-moe-l2.json").write_text(json.dumps(cfg))
+    (tree / "bench/workloads/serve.tiny.json").write_text(json.dumps(
+        {"config": "tiny-moe-l2", "driver": "probe_serve", "chips": 1}))
+    b = json.loads((tree / "BENCHMARK.json").read_text())
+    b["configs"].append({"name": "tiny-moe-l2", "source": "x",
+                         "file": "bench/configs/tiny-moe-l2.json",
+                         "reduced": [], "why": "x"})
+    b["workloads"].append({"name": "serve.tiny", "config": "tiny-moe-l2",
+                           "traffic": "serve.tiny", "chips": 1, "why": "x"})
+    (tree / "BENCHMARK.json").write_text(json.dumps(b))
+
+    w = harness.workload("serve.tiny")
+    assert callable(harness.driver(w["driver"]).run)
+    found = harness.config(w["config"])
+    assert found == cfg
+    harness.load_repro()
+    from repro.models.model import Model
+    mc = harness.arch(found).model_config(found)
+    assert (mc.family, mc.n_experts, mc.experts_per_token) == ("moe", 4, 2)
+    experts = {jax.tree_util.keystr(p): x.shape for p, x in
+               jax.tree_util.tree_flatten_with_path(
+                   Model(mc).abstract_params())[0] if "moe" in
+               jax.tree_util.keystr(p)}
+    moe = "['stack']['layers']['moe']"
+    assert experts == {moe + "['router']": (2, 32, 4),
+                       moe + "['wi_gate']": (2, 4, 32, 16),
+                       moe + "['wi_up']": (2, 4, 32, 16),
+                       moe + "['wo']": (2, 4, 16, 32)}
+    # the configuration that was there keeps its dense module
+    assert harness.arch(harness.config("qwen2-0.5b-l4")).__name__ \
+        == "bench_arch_dense"
+    after = _digests(tree / "bench")
+    assert {k: v for k, v in after.items() if k in before} == before
+
+
+def test_an_architecture_with_no_module_is_an_error(tree):
+    cfg = dict(harness.config("qwen2-0.5b-l4"), arch="no_such_arch")
+    with pytest.raises(harness.BenchError, match="no_such_arch.*dense"):
+        harness.arch(cfg)

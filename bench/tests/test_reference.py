@@ -1,7 +1,8 @@
 """The plain reference against the program's Model at a small size on the
 CPU, with the program computing in float32 so that the two must agree to
 rounding: logits, loss, gradients and AdamW, with QKV bias, grouped-query
-attention and the tied head."""
+attention and the tied head. The model, weights and reference come from
+the configuration's architecture module, as a run finds them."""
 import dataclasses
 
 import jax
@@ -9,8 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness, weights
+from bench import harness
 from bench.reference import dense
+from bench.weights import check_layout
 from bench.tests.tiny import TINY_DENSE
 
 CONFIGS = {
@@ -23,7 +25,7 @@ CONFIGS = {
 def _program(cfg):
     harness.load_repro()
     from repro.models.model import Model
-    mc = dataclasses.replace(harness.model_config(cfg),
+    mc = dataclasses.replace(harness.arch(cfg).model_config(cfg),
                              compute_dtype="float32")
     return Model(mc)
 
@@ -38,32 +40,34 @@ def _batch(cfg, seed=0, rows=2, seq=24):
 @pytest.fixture(params=sorted(CONFIGS))
 def case(request):
     cfg = CONFIGS[request.param]
-    return cfg, _program(cfg), weights.make(cfg, harness.seed_key(3, 1))
+    arch = harness.arch(cfg)
+    return (cfg, _program(cfg), arch.make_weights(cfg, harness.seed_key(3, 1)),
+            arch)
 
 
 def test_weights_match_program_layout(case):
-    cfg, model, params = case
-    weights.check_layout(params, model.abstract_params())
+    cfg, model, params, _ = case
+    check_layout(params, model.abstract_params())
 
 
 def test_logits(case):
-    cfg, model, params = case
+    cfg, model, params, arch = case
     b = _batch(cfg)
     with jax.default_matmul_precision("highest"):
         got, _ = model.logits(params, b)
-        ref = dense.logits(params, dense.hidden(params, b["tokens"], cfg))
+        ref = arch.logits(params, arch.hidden(params, b["tokens"], cfg))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
 
 def test_loss_and_grads(case):
-    cfg, model, params = case
+    cfg, model, params, arch = case
     b = _batch(cfg, seed=1)
     with jax.default_matmul_precision("highest"):
         (loss, _), grads = jax.value_and_grad(
             lambda p: model.loss_fn(p, b), has_aux=True)(params)
-        rloss, rgrads = dense.loss_and_grads(params, b["tokens"],
-                                             b["labels"], cfg)
+        rloss, rgrads = arch.loss_and_grads(params, b["tokens"],
+                                            b["labels"], cfg)
     assert abs(float(loss) - float(rloss)) < 1e-4 * abs(float(rloss))
     for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(rgrads)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),
@@ -71,7 +75,8 @@ def test_loss_and_grads(case):
 
 
 def test_adamw_matches_program_optimizer(case):
-    cfg, model, params = case
+    """The AdamW that the dense reference's `train` runs."""
+    cfg, model, params, _ = case
     from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update
     opt = {"lr": 3e-4, "b1": 0.9, "b2": 0.95, "eps": 1e-8,
            "weight_decay": 0.1, "clip_norm": 1.0, "warmup_steps": 2,
@@ -96,10 +101,10 @@ def test_adamw_matches_program_optimizer(case):
 
 
 def test_fp8_control_departs_from_float32(case):
-    cfg, model, params = case
+    cfg, model, params, arch = case
     b = _batch(cfg, seed=2)
     with jax.default_matmul_precision("highest"):
-        hi = dense.hidden(params, b["tokens"], cfg, "f32")
-        lo = dense.hidden(params, b["tokens"], cfg, "fp8")
+        hi = arch.hidden(params, b["tokens"], cfg, "f32")
+        lo = arch.hidden(params, b["tokens"], cfg, "fp8")
     rel = float(jnp.linalg.norm(hi - lo) / jnp.linalg.norm(hi))
     assert 1e-3 < rel < 0.5
