@@ -22,12 +22,13 @@ in the checkpoint substrate):
           serde frames by a thread pool, one worker per shard. Sync and
           async saves share the same on-device digest path — a sync save
           never host-hashes bytes the device already digested.
-  async   save() snapshots the state with a cheap on-device copy (so the
-          trainer may donate its buffers to step N+1 immediately), kicks
-          the device→host DMA per leaf, and queues serialization + IO on
-          a single ordered writer thread. A bounded queue of depth 2
-          double-buffers snapshots: snapshot N drains while step N+1
-          runs; save(N+2) blocks only if N hasn't committed yet.
+  async   save() takes the caller's device buffers as the snapshot (the
+          caller hands over a copy of its own that no step donates: the
+          trainer's `local`), kicks the device→host DMA per leaf, and
+          queues serialization + IO on a single ordered writer thread.
+          A bounded queue of depth 2 double-buffers snapshots: snapshot
+          N drains while step N+1 runs; save(N+2) blocks only if N
+          hasn't committed yet.
   read    shards are memory-mapped (no read syscalls for the bulk data)
           and digest-verified per-shard in parallel before the views are
           stitched back into a pytree.
@@ -95,20 +96,18 @@ from .manifest import (Manifest, digest_from_checksum, flatten_leaves,
 
 
 def _snapshot_device(leaf, *, kick: bool = True):
-    """On-device copy + (optional) async D2H kick. The copy decouples the
-    snapshot from donation: step N+1 may donate the original buffer while
-    the copy drains. With kick=False the copy stays on device — the
-    gather path moves only dirty tiles later, so kicking the full drain
-    here would defeat it. Returns an object np.asarray() can materialize
-    later."""
+    """The leaf as the async snapshot, with its D2H kicked. The caller's
+    buffer is not copied: nothing may donate it until the write is done.
+    With kick=False the snapshot stays on device — the gather path moves
+    only dirty tiles later, so kicking the full drain here would defeat
+    it. Returns an object np.asarray() can materialize later."""
     if isinstance(leaf, jax.Array):
-        c = jax.numpy.copy(leaf)
         if kick:
             try:
-                c.copy_to_host_async()
+                leaf.copy_to_host_async()
             except (AttributeError, RuntimeError):
                 pass
-        return c
+        return leaf
     return np.asarray(leaf)
 
 
@@ -303,7 +302,9 @@ class FileCheckpointer:
         the full D2H drain kicked only when the chain planner says the
         full bytes will be needed; serialization and IO run on the
         writer thread. Up to one snapshot queues behind the one draining
-        (double buffering); further saves block on the oldest.
+        (double buffering); further saves block on the oldest. An async
+        save reads `state`'s device buffers after it returns: no step may
+        donate them until the write is done.
         """
         with span("ckpt.save", step=step):
             self._save(step, state, async_, extra)

@@ -223,8 +223,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         hlo = compiled.as_text()
         colls = collective_summary(hlo)
         whiles = while_report(hlo)
-        ac = cell_cost(cfg, shape, flash=(ec.attn_impl == "pallas"),
-               moe_group=ec.moe_group)
+        ac = cell_cost(cfg, shape, flash=(ec.attn_impl == "pallas"))
         result = {
             "arch": arch, "shape": shape_name, "mesh": mesh_name,
             "chips": mesh.size,

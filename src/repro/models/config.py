@@ -28,12 +28,18 @@ class ModelConfig:
     mlp_gated: bool = True      # False -> 2-matmul (up, down) MLP
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
-    tie_embeddings: bool = False
+    # False gives the output head a leaf of its own; the presets that do
+    # not say so unembed through the embedding, as they always have
+    tie_embeddings: bool = True
 
-    # MoE (d_ff is the per-expert width for moe archs)
+    # MoE (d_ff is the per-expert width for moe archs). The router spans
+    # n_experts; this chip holds experts_held of them (0: all), from
+    # expert_offset. router_aux_coef weighs the load-balancing loss.
     n_experts: int = 0
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0
+    expert_offset: int = 0
+    router_aux_coef: float = 0.01
 
     # SSM (mamba1/mamba2)
     ssm_state: int = 0
@@ -68,6 +74,11 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -95,6 +106,8 @@ class ModelConfig:
         p += (self.n_heads * hd) * self.d_model           # o
         if self.qkv_bias:
             p += (self.n_heads + 2 * self.n_kv_heads) * hd
+        if self.qk_norm:
+            p += 2 * hd
         return p
 
     def _mlp_params(self) -> int:
@@ -102,8 +115,13 @@ class ModelConfig:
         return m * self.d_model * self.d_ff               # (gate,) up, down
 
     def _moe_params(self, active_only: bool) -> int:
-        e = self.experts_per_token if active_only else self.n_experts
-        return self.d_model * self.n_experts + e * 3 * self.d_model * self.d_ff
+        """The router at its full width and the experts held here (those a
+        token activates: the held share of its top-k)."""
+        e = self.n_held
+        if active_only:
+            e = self.experts_per_token * self.n_held / self.n_experts
+        return int(self.d_model * self.n_experts
+                   + e * 3 * self.d_model * self.d_ff)
 
     def _mamba_params(self) -> int:
         di, ds = self.d_inner, self.ssm_state
@@ -124,6 +142,7 @@ class ModelConfig:
         """Approximate parameter count; active_only counts routed experts only."""
         emb = self.vocab_size * self.d_model
         total = emb if self.tie_embeddings else 2 * emb
+        total += self.d_model                              # final norm
         if self.frontend:
             total += self.d_model  # stub projection scale only
 

@@ -59,16 +59,15 @@ def mlp_flops(cfg: ModelConfig, B: int, S: int) -> float:
     return m * 2 * B * S * cfg.d_model * cfg.d_ff
 
 
-def moe_flops(cfg: ModelConfig, B: int, S: int, group: int = 512) -> float:
+def moe_flops(cfg: ModelConfig, B: int, S: int) -> float:
+    """Dropless routing: the router at its full width, and the three
+    expert products over the rows the held experts expect, T·k·held/E
+    (every choice computed; the sort and gathers move rows, no FLOPs)."""
     T = B * S
     E, k, D, F = cfg.n_experts, cfg.experts_per_token, cfg.d_model, cfg.d_ff
-    g = min(group, T)
-    cap = max(int(cfg.capacity_factor * k * g / E), 4)
     router = 2 * T * D * E
-    # dispatch + combine one-hot einsums (GShard formulation cost)
-    dispatch = 2 * 2 * T * E * cap * D
-    experts = 3 * 2 * (T // g * E * cap) * D * F
-    return router + dispatch + experts
+    experts = 3 * 2 * (T * k * cfg.n_held / E) * D * F
+    return router + experts
 
 
 def mamba_flops(cfg: ModelConfig, B: int, S: int) -> float:
@@ -90,8 +89,7 @@ def mamba_flops(cfg: ModelConfig, B: int, S: int) -> float:
     return f
 
 
-def _block_flops(cfg: ModelConfig, B: int, S: int, *, flash: bool,
-                 moe_group: int = 512) -> float:
+def _block_flops(cfg: ModelConfig, B: int, S: int, *, flash: bool) -> float:
     """One decoder layer, forward."""
     fam = cfg.family
     if fam in ("dense", "vlm"):
@@ -99,7 +97,7 @@ def _block_flops(cfg: ModelConfig, B: int, S: int, *, flash: bool,
             + mlp_flops(cfg, B, S)
     if fam == "moe":
         return attn_flops(cfg, B, S, S, causal=True, flash=flash) \
-            + moe_flops(cfg, B, S, group=moe_group)
+            + moe_flops(cfg, B, S)
     if fam == "ssm":
         return mamba_flops(cfg, B, S)
     if fam == "hybrid":
@@ -112,9 +110,8 @@ def _block_flops(cfg: ModelConfig, B: int, S: int, *, flash: bool,
 
 
 def forward_flops(cfg: ModelConfig, B: int, S: int, *,
-                  flash: bool = False, moe_group: int = 512) -> float:
-    f = cfg.n_layers * _block_flops(cfg, B, S, flash=flash,
-                                    moe_group=moe_group)
+                  flash: bool = False) -> float:
+    f = cfg.n_layers * _block_flops(cfg, B, S, flash=flash)
     if cfg.family == "hybrid":
         G = cfg.n_layers // cfg.attn_every
         f += G * (attn_flops(cfg, B, S, S, causal=True, flash=flash)
@@ -194,8 +191,7 @@ def kv_cache_bytes(cfg: ModelConfig, B: int, Smax: int) -> float:
 
 
 def cell_cost(cfg: ModelConfig, shape: ShapeConfig, *,
-              flash: bool = False, remat: bool = True,
-              moe_group: int = 512) -> CellCost:
+              flash: bool = False, remat: bool = True) -> CellCost:
     """Roofline terms for one step of this cell (totals across the mesh)."""
     B, S = shape.global_batch, shape.seq_len
     P = cfg.param_count()
@@ -204,7 +200,7 @@ def cell_cost(cfg: ModelConfig, shape: ShapeConfig, *,
     d = {}
 
     if shape.kind == "train":
-        fwd = forward_flops(cfg, B, S, flash=flash, moe_group=moe_group)
+        fwd = forward_flops(cfg, B, S, flash=flash)
         flops = 3 * fwd
         if remat:
             # recompute the layer stack (not the unembed) in backward
@@ -220,7 +216,7 @@ def cell_cost(cfg: ModelConfig, shape: ShapeConfig, *,
         d = {"fwd_flops": fwd, "param_traffic": param_traffic, "act": act}
 
     elif shape.kind == "prefill":
-        flops = forward_flops(cfg, B, S, flash=flash, moe_group=moe_group)
+        flops = forward_flops(cfg, B, S, flash=flash)
         act = 10 * cfg.n_layers * B * S * cfg.d_model * 2
         hbm = P * pbytes + act + kv_cache_bytes(cfg, B, S)
         d = {"kv_write": kv_cache_bytes(cfg, B, S)}

@@ -90,12 +90,20 @@ class Model:
             "stack": stack_init(k_stack, cfg, dtype),
             "ln_f": rmsnorm_init(cfg.d_model, dtype),
         }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = embedding_init(
+                jax.random.fold_in(k_emb, 1), cfg.vocab_size, cfg.d_model,
+                dtype)
         if cfg.frontend:
             params["frontend_proj"] = dense_init(
                 k_front, cfg.d_model, cfg.d_model, dtype)
         if cfg.family == "encdec":
             params["ln_enc"] = rmsnorm_init(cfg.d_model, dtype)
         return params
+
+    def head(self, params) -> Params:
+        """The output head: its own leaf, or the embedding where tied."""
+        return params["embedding" if self.cfg.tie_embeddings else "lm_head"]
 
     def abstract_params(self) -> Params:
         """ShapeDtypeStruct pytree of the parameters (no allocation)."""
@@ -114,7 +122,10 @@ class Model:
         return x
 
     def forward(self, params, batch):
-        """Full-sequence forward -> (hidden (B,S,D), aux_loss)."""
+        """Full-sequence forward -> (hidden (B,S,D), aux): aux["aux"] is
+        the router's load-balancing loss (0 without routed experts), and
+        aux["expert_rows"] (layers, held experts) the rows each held
+        expert computed."""
         cfg, ec = self.cfg, self.ec
         dt = _dt(cfg)
         x = self._embed_inputs(params, batch, dt)
@@ -127,23 +138,31 @@ class Model:
             enc_out = rmsnorm(params["ln_enc"], enc_out, cfg.norm_eps)
         h, aux = stack_forward(params["stack"], x, cfg, ec, positions, dt,
                                enc_out=enc_out)
+        if cfg.is_moe:
+            aux = {"aux": moe_mod.load_balance_loss(
+                       aux["counts"], aux["probs"],
+                       x.shape[0] * S * cfg.n_layers),
+                   "expert_rows": aux["rows"]}
+        else:
+            aux = {"aux": aux}
         h = rmsnorm(params["ln_f"], h, cfg.norm_eps)
         return h, aux
 
     def logits(self, params, batch):
         """(B,S,V) logits — small-model/test path only."""
         h, aux = self.forward(params, batch)
-        return unembed(params["embedding"], h, _dt(self.cfg)), aux
+        return unembed(self.head(params), h, _dt(self.cfg)), aux
 
     def loss_fn(self, params, batch):
-        """Mean token cross-entropy + MoE aux. Returns (loss, metrics)."""
+        """Mean token cross-entropy + the router's load-balancing loss
+        times its coefficient. Returns (loss, metrics)."""
         cfg = self.cfg
         h, aux = self.forward(params, batch)
-        xent = masked_chunked_xent(params["embedding"]["table"], h,
+        xent = masked_chunked_xent(self.head(params)["table"], h,
                                    batch["labels"], _dt(cfg),
                                    n_chunks=self.ec.xent_chunks)
-        loss = xent + 0.01 * aux
-        return loss, {"xent": xent, "aux": aux}
+        loss = xent + cfg.router_aux_coef * aux["aux"]
+        return loss, {"xent": xent, **aux}
 
     # ------------------------------------------------------- decode state
 
@@ -222,8 +241,7 @@ class Model:
                 h = h + o
                 hn = rmsnorm(lp["ln2"], h, cfg.norm_eps)
                 if fam == "moe":
-                    y, _ = moe_mod.moe_mlp(lp["moe"], hn, cfg, dt,
-                                           group_size=self.ec.moe_group)
+                    y, _ = moe_mod.moe_mlp(lp["moe"], hn, cfg, dt)
                 else:
                     from .layers import mlp
                     y = mlp(lp["mlp"], hn, dt)
@@ -310,7 +328,7 @@ class Model:
             raise ValueError(fam)
 
         h = rmsnorm(params["ln_f"], x[:, -1:], cfg.norm_eps)
-        logits = unembed(params["embedding"], h, dt)
+        logits = unembed(self.head(params), h, dt)
         return logits, state
 
     # -------------------------------------------------------- decode step
@@ -338,8 +356,7 @@ class Model:
                 h = h + o
                 hn = rmsnorm(lp["ln2"], h, cfg.norm_eps)
                 if fam == "moe":
-                    y, _ = moe_mod.moe_mlp(lp["moe"], hn, cfg, dt,
-                                           group_size=self.ec.moe_group)
+                    y, _ = moe_mod.moe_mlp(lp["moe"], hn, cfg, dt)
                 else:
                     from .layers import mlp
                     y = mlp(lp["mlp"], hn, dt)
@@ -423,7 +440,7 @@ class Model:
             raise ValueError(fam)
 
         h = rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        logits = unembed(params["embedding"], h, dt)
+        logits = unembed(self.head(params), h, dt)
         return logits, new_state
 
     # ------------------------------------------------- decode state specs

@@ -35,7 +35,6 @@ class ExecConfig:
     scan_layers: bool = True
     microbatches: int = 1             # grad-accumulation inner loop
     seq_parallel: bool = False        # sequence-shard the residual stream
-    moe_group: int = 256              # MoE routing group size (tokens)
 
 
 def _remat(fn, policy: str):
@@ -105,9 +104,9 @@ def moe_block(p, x, cfg: ModelConfig, ec: ExecConfig, positions, dt):
         attn_mod.attention(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps),
                            cfg, positions=positions, impl=ec.attn_impl,
                            compute_dtype=dt), "attn_out")
-    y, aux = moe_mod.moe_mlp(p["moe"], rmsnorm(p["ln2"], h, cfg.norm_eps),
-                             cfg, dt, group_size=ec.moe_group)
-    return h + checkpoint_name(y, "moe_out"), aux
+    y, stats = moe_mod.moe_mlp(p["moe"], rmsnorm(p["ln2"], h, cfg.norm_eps),
+                               cfg, dt)
+    return h + checkpoint_name(y, "moe_out"), stats
 
 
 def mamba_block_init(key, cfg: ModelConfig, dtype):
@@ -185,22 +184,28 @@ def stack_init(key, cfg: ModelConfig, dtype) -> Params:
 
 # ---------------------------------------------------------- stack: forward
 
-def _scan_blocks(body, x, layers, ec: ExecConfig):
+def _scan_layers(body, x, layers, ec: ExecConfig):
+    """(x, each layer's second output stacked on a leading axis)."""
     body = _remat(body, ec.remat_policy)
     if ec.scan_layers:
-        x, aux = jax.lax.scan(body, x, layers)
-        return x, jnp.sum(aux)
+        return jax.lax.scan(body, x, layers)
     n = jax.tree.leaves(layers)[0].shape[0]
-    aux_total = jnp.zeros((), jnp.float32)
+    outs = []
     for i in range(n):
-        x, aux = body(x, jax.tree.map(lambda a: a[i], layers))
-        aux_total = aux_total + aux
-    return x, aux_total
+        x, out = body(x, jax.tree.map(lambda a: a[i], layers))
+        outs.append(out)
+    return x, jax.tree.map(lambda *o: jnp.stack(o), *outs)
+
+
+def _scan_blocks(body, x, layers, ec: ExecConfig):
+    x, aux = _scan_layers(body, x, layers, ec)
+    return x, jnp.sum(aux)
 
 
 def stack_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig, ec: ExecConfig,
                   positions, dt, enc_out: Optional[jnp.ndarray] = None):
-    """x: (B,S,D) -> ((B,S,D), aux_loss)."""
+    """x: (B,S,D) -> ((B,S,D), aux): a loss scalar (0 but for moe); for
+    moe the router's per-layer sums (`moe_mlp`'s stats, stacked)."""
     fam = cfg.family
 
     if fam in ("dense", "vlm"):
@@ -210,9 +215,8 @@ def stack_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig, ec: ExecConfig,
 
     if fam == "moe":
         def body(h, lp):
-            h, aux = moe_block(lp, h, cfg, ec, positions, dt)
-            return h, aux
-        return _scan_blocks(body, x, p["layers"], ec)
+            return moe_block(lp, h, cfg, ec, positions, dt)
+        return _scan_layers(body, x, p["layers"], ec)
 
     if fam == "ssm":
         def body(h, lp):

@@ -90,8 +90,8 @@ PRESETS = {
 # automatically for stacked-layer params (rank == len(axes) + 1).
 
 PARAM_RULES: list[tuple[str, tuple]] = [
-    # embeddings / unembedding: vocab × embed
-    (r"embedding/table$",        ("vocab", "embed")),
+    # embeddings / the untied output head: vocab × embed
+    (r"(embedding|lm_head)/table$", ("vocab", "embed")),
     # attention projections
     (r"attn/wq$|cross/wq$",      ("embed", "heads")),
     (r"attn/wk$|cross/wk$",      ("embed", "kv_heads")),
@@ -102,7 +102,8 @@ PARAM_RULES: list[tuple[str, tuple]] = [
     # dense mlp
     (r"mlp/wi_(gate|up)$",       ("embed", "heads")),
     (r"mlp/wo$",                 ("heads", "embed")),
-    # MoE: expert-sharded tables; router replicated on its output axis
+    # MoE: the held experts' tables, expert-sharded; the router replicated
+    # on its output axis (it spans every expert)
     (r"moe/router$",             ("embed", None)),
     (r"moe/wi_(gate|up)$",       ("expert", "embed", None)),
     (r"moe/wo$",                 ("expert", None, "embed")),

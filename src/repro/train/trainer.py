@@ -126,6 +126,9 @@ class Trainer:
         self.shadow_ckpt: Optional[tuple[int, Any]] = None
         self.state: Optional[dict] = None
         self.logs: list[StepLog] = []
+        # the newest step's metrics (loss parts, the MoE layers'
+        # expert_rows), left on the device: nothing reads them per step
+        self.last_metrics: Optional[dict] = None
         self.reports: list[RecoveryReport] = []
         self.straggler = StragglerTracker()
         # gray-failure plan from the injector's scenario (if any): the
@@ -226,13 +229,19 @@ class Trainer:
             self._handle_failure(failure)
             raise RollbackSignal(self.view.epoch)
         state = self.state
+        # the new copies replace the memory tier: release the previous
+        # ones first, so the device never holds two generations of them
+        # (a fault from here on restores the file this save commits)
+        self.mem_ckpt = None
         with span("save.copies"):
             if self.mesh is not None and self.mesh.shape.get("data", 1) > 1:
                 buddy = buddy_exchange(state, self.mesh, self.rules)
             else:
                 buddy = jax.tree.map(lambda a: a + 0, state)  # device copy
             local = jax.tree.map(lambda a: a + 0, state)
-        self.file_ckpt.save(step, state, async_=self.policy.async_file)
+        # the file snapshot is `local` itself, which no step donates: the
+        # device holds the state and its two copies, not a third
+        self.file_ckpt.save(step, local, async_=self.policy.async_file)
         failure = self._injected_at("worker.ckpt.pre_push", step)
         if failure is not None:
             # ReStore's mid-replication failure: the file committed but
@@ -554,7 +563,8 @@ class Trainer:
         with span("train.feed") as feed:
             batch = self.data.batch(step)
         with span("train.dispatch") as dispatch:
-            self.state, (loss, _) = self._step(self.state, batch)
+            self.state, (loss, self.last_metrics) = self._step(self.state,
+                                                               batch)
         with span("train.wait") as wait:
             jax.block_until_ready(self.state["params"])
         dt = feed.seconds + dispatch.seconds + wait.seconds

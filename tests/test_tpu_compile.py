@@ -8,6 +8,7 @@ inside a fixture, so only the worker that runs these tests loads the
 TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -85,13 +86,18 @@ def test_flash_attention_forward_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_flash_attention_backward_compiles(one_chip):
+@pytest.mark.parametrize("q_shape,kv_shape", [
+    pytest.param((4, 1024, 14, 64), (4, 1024, 2, 64), id="train.ckpt"),
+    pytest.param((2, 4096, 32, 128), (2, 4096, 4, 128),
+                 id="train.moe.ckpt")])
+def test_flash_attention_backward_compiles(one_chip, q_shape, kv_shape):
     """Forward and backward (jax.grad through the custom VJP) at the
-    widths of the train.ckpt cell, qwen2-0.5b-l4: batch 4 x 1024, 14
-    query and 2 KV heads of 64, with the blocks the model path picks."""
+    widths of the training cells, with the blocks the model path picks:
+    qwen2-0.5b-l4 (batch 4 x 1024, 14 query and 2 KV heads of 64) and
+    qwen3-30b-a3b-l4e8 (batch 2 x 4096, 32 query and 4 KV heads of
+    128)."""
     from repro.kernels.flash_attention.ops import flash_attention
     from repro.models.attention import flash_blocks
-    q_shape, kv_shape = (4, 1024, 14, 64), (4, 1024, 2, 64)
     bq, bk = flash_blocks(q_shape, kv_shape, platform="tpu")
 
     def loss(q, k, v):
@@ -105,6 +111,37 @@ def test_flash_attention_backward_compiles(one_chip):
              if 'custom_call_target="tpu_custom_call"' in line]
     for kernel in ("flash_fwd", "flash_bwd_dkv"):
         assert any(kernel in line.split("=")[0] for line in calls), kernel
+
+
+@pytest.mark.parametrize("d_in,d_out", [(2048, 768), (768, 2048)])
+def test_grouped_matmul_forward_and_backward_compile(one_chip, d_in, d_out):
+    """The MoE layer's grouped matmul at the widths of train.moe.ckpt:
+    4,096 rows (the 8 held experts' expected rows of a step's layer) and
+    a tail of rows of no held expert, over 8 groups, forward and
+    backward, with the tiling the layer picks. The kernels' names hold
+    what gmm_roofline matches: `gmm` (forward, input gradient) and
+    `tgmm` (weight gradient), after the scope they are called in."""
+    from repro.models.moe import grouped_matmul, gmm_tiling
+    rows = 4096 + 512
+    tiling = gmm_tiling(rows, d_in, d_out, platform="tpu")
+    assert tiling is not None
+
+    def loss(x, w, sizes):
+        y = grouped_matmul(x, w, sizes, tiling=tiling)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = _compiled_text(
+        jax.grad(loss, (0, 1)),
+        _shape(one_chip, (rows, d_in), jnp.bfloat16),
+        _shape(one_chip, (8, d_in, d_out), jnp.bfloat16),
+        _shape(one_chip, (9,), jnp.int32))
+    named = {line.split("=")[0].strip(): 'custom_call_target="tpu_' \
+             'custom_call"' in line for line in text.splitlines()
+             if re.match(r"\s*(ROOT )?%\S*gmm\S* = ", line)}
+    # the reader's pattern finds the kernels and nothing else
+    assert named and all(named.values()), named
+    assert any("tgmm" in n for n in named), named
+    assert any("gmm" in n.replace("tgmm", "") for n in named), named
 
 
 def test_selective_scan_compiles(one_chip):

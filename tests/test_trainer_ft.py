@@ -4,6 +4,8 @@ The strongest property the paper's design implies: with a step-indexed
 data pipeline and per-step checkpoints, a failure-and-recovery run must
 converge to the BIT-IDENTICAL final state of an uninterrupted run.
 """
+import dataclasses
+
 import jax
 import pytest
 
@@ -14,18 +16,30 @@ from repro.models.model import Model
 from repro.train import AdamWConfig, TokenPipeline, TrainConfig, Trainer
 
 CFG = reduced(get_config("paper-demo"))
+# Qwen3-MoE at a tiny width: a router over 16 experts, 4 of them held
+# (from the fifth), top-4, QK-norm, an untied head
+QWEN3_MOE = dataclasses.replace(
+    reduced(get_config("qwen3-moe-30b-a3b")), n_experts=16,
+    experts_per_token=4, experts_held=4, expert_offset=4)
+CONFIGS = {"paper-demo": CFG, "qwen3-moe": QWEN3_MOE}
 STEPS = 10
 
 
-def _run(tmp_path, strategy, injector=None, tag=""):
-    model = Model(CFG)
-    data = TokenPipeline(CFG.vocab_size, 4, 32, seed=7)
+def _run(tmp_path, strategy, injector=None, tag="", cfg=CFG):
+    model = Model(cfg)
+    data = TokenPipeline(cfg.vocab_size, 4, 32, seed=7)
     opt = AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=STEPS)
     tc = TrainConfig(total_steps=STEPS, ckpt_dir=str(tmp_path / tag),
                      strategy=strategy)
     tr = Trainer(model, data, opt, tc, injector=injector)
     res = tr.run()
     return tr, res
+
+
+def _state_digest(tr):
+    """Parameters and AdamW moments, bit for bit."""
+    return tree_digest(jax.device_get(tr.state["params"])), \
+        tree_digest(jax.device_get(tr.state["opt"]))
 
 
 @pytest.fixture(scope="module")
@@ -35,30 +49,59 @@ def reference(tmp_path_factory):
     return tree_digest(jax.device_get(tr.state["params"])), res
 
 
-@pytest.mark.parametrize("strategy", ["reinit", "ulfm", "cr"])
+@pytest.fixture(scope="module")
+def state_references(tmp_path_factory):
+    """A fault-free run's final state digests, per configuration."""
+    done = {}
+
+    def get(name):
+        if name not in done:
+            tr, _ = _run(tmp_path_factory.mktemp(f"ref-{name}"), "reinit",
+                         tag="ref", cfg=CONFIGS[name])
+            done[name] = _state_digest(tr)
+        return done[name]
+    return get
+
+
+@pytest.mark.parametrize("strategy,cfg_name", [
+    pytest.param("reinit", "paper-demo", id="reinit"),
+    pytest.param("ulfm", "paper-demo", id="ulfm"),
+    pytest.param("cr", "paper-demo", id="cr"),
+    pytest.param("reinit", "qwen3-moe", id="reinit-qwen3-moe"),
+    pytest.param("cr", "qwen3-moe", id="cr-qwen3-moe")])
 def test_bitwise_identical_recovery_process_failure(tmp_path, strategy,
-                                                    reference):
-    ref_digest, _ = reference
+                                                    cfg_name,
+                                                    state_references):
+    """After a killed process the run continues bit-identically: for the
+    MoE configuration the router, the held experts, the untied head and
+    their AdamW moments come back from the buddy copy (reinit) or the
+    file (cr)."""
     inj = FaultInjector(n_ranks=8, n_steps=STEPS,
                         kind=FailureType.PROCESS, seed=3)
-    tr, res = _run(tmp_path, strategy, injector=inj, tag=strategy)
+    tr, res = _run(tmp_path, strategy, injector=inj, tag=strategy,
+                   cfg=CONFIGS[cfg_name])
     assert res["final_step"] == STEPS
     assert len(res["reports"]) == 1
     rep = res["reports"][0]
     assert rep.rollback_step == inj.fail_step
-    assert tree_digest(jax.device_get(tr.state["params"])) == ref_digest
+    assert _state_digest(tr) == state_references(cfg_name)
 
 
-@pytest.mark.parametrize("strategy", ["reinit", "cr"])
+@pytest.mark.parametrize("strategy,cfg_name", [
+    pytest.param("reinit", "paper-demo", id="reinit"),
+    pytest.param("cr", "paper-demo", id="cr"),
+    pytest.param("reinit", "qwen3-moe", id="reinit-qwen3-moe"),
+    pytest.param("cr", "qwen3-moe", id="cr-qwen3-moe")])
 def test_bitwise_identical_recovery_node_failure(tmp_path, strategy,
-                                                 reference):
-    ref_digest, _ = reference
+                                                 cfg_name,
+                                                 state_references):
     inj = FaultInjector(n_ranks=8, n_steps=STEPS, kind=FailureType.NODE,
                         seed=5)
-    tr, res = _run(tmp_path, strategy, injector=inj, tag=strategy)
+    tr, res = _run(tmp_path, strategy, injector=inj, tag=strategy,
+                   cfg=CONFIGS[cfg_name])
     assert res["final_step"] == STEPS
     # node failure forces the FILE checkpoint path (Table 2)
-    assert tree_digest(jax.device_get(tr.state["params"])) == ref_digest
+    assert _state_digest(tr) == state_references(cfg_name)
 
 
 def test_cr_recovery_through_delta_checkpoints(tmp_path):
