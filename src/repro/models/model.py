@@ -125,7 +125,8 @@ class Model:
         """Full-sequence forward -> (hidden (B,S,D), aux): aux["aux"] is
         the router's load-balancing loss (0 without routed experts), and
         aux["expert_rows"] (layers, held experts) the rows each held
-        expert computed."""
+        expert computed and aux["expert_full"] (layers,) 1 where a layer
+        ran its full-size core (`moe_mlp`'s stats)."""
         cfg, ec = self.cfg, self.ec
         dt = _dt(cfg)
         x = self._embed_inputs(params, batch, dt)
@@ -142,7 +143,7 @@ class Model:
             aux = {"aux": moe_mod.load_balance_loss(
                        aux["counts"], aux["probs"],
                        x.shape[0] * S * cfg.n_layers),
-                   "expert_rows": aux["rows"]}
+                   "expert_rows": aux["rows"], "expert_full": aux["full"]}
         else:
             aux = {"aux": aux}
         h = rmsnorm(params["ln_f"], h, cfg.norm_eps)

@@ -11,10 +11,25 @@ exchange of its own: on one chip it computes its own experts' part of the
 result and that partial result goes on to the next layer.
 
 The pairs are sorted by expert (held experts first, in order; pairs of
-absent experts last), the three products run as one grouped matmul each
-over the held experts, and the weighted rows are gathered back per token.
-Both moves are gathers by a permutation, so the backward pass is a gather
-by the inverse permutation and never a scatter.
+absent experts last) and the three products run as one grouped matmul
+each over the held experts. T tokens can send up to T·min(k, n_held) rows
+here, but they are expected to send T·k·n_held/n_experts (4,096 of 65,536
+for 8,192 tokens, top-8, 8 of 128 experts held). So the sorted buffer has
+`row_bound` rows: twice the expected rows, in whole row tiles. Its rows
+past the held count are pairs of absent experts; they carry weight 0 and
+the grouped matmul leaves them zero. The core gathers its rows by token
+and sums the weighted products back into their tokens (a segment sum in
+float32); each backward pass is the other move, so no buffer of T·k rows
+is written.
+
+A call whose held rows exceed the bound runs the same core on every row
+the tokens can send instead (a `lax.cond` on the held count;
+`stats["full"]`), so the layer stays dropless for any routing. Where the
+bound is T·min(k, n_held) (every expert held, decode-sized batches) the
+layer is that core alone, with no branch. The branch is a
+`jax.custom_vjp` that saves only the core's inputs and, backward, runs
+`jax.vjp` of the core its forward ran: a differentiated `lax.cond` would
+write the untaken core's residuals, at full size, as zeros.
 
 The grouped matmul is the Pallas kernel `gmm` (megablox, with its own
 backward: `gmm` for the input, `tgmm` for the weights) on a TPU, off a
@@ -72,42 +87,25 @@ def load_balance_loss(counts: jnp.ndarray, prob_sums: jnp.ndarray,
     return E * jnp.sum(c * p)
 
 
-# ---------------------------------------------------------- permutations
+# ---------------------------------------------------------- row moves
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inv, k: int):
-    """Rows x[order // k]: each token once per choice, in `order` (a
-    permutation of the T*k pairs, `inv` its inverse)."""
-    return x[order // k]
-
-
-def _dispatch_fwd(x, order, inv, k):
-    return x[order // k], inv
-
-
-def _dispatch_bwd(k, inv, g):
-    T = inv.shape[0] // k
-    return g[inv].reshape(T, k, -1).sum(axis=1), None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _permute(x, idx, inv):
-    """Rows x[idx] for a permutation `idx` whose inverse is `inv`."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_rows(x, idx, n: int):
+    """Rows x[idx] of x's n rows; the backward sums each row's gradient
+    back into its row of x, in float32."""
     return x[idx]
 
 
-def _permute_fwd(x, idx, inv):
-    return x[idx], inv
+def _take_rows_fwd(x, idx, n):
+    return x[idx], idx
 
 
-def _permute_bwd(inv, g):
-    return g[inv], None, None
+def _take_rows_bwd(n, idx, g):
+    return jax.ops.segment_sum(g.astype(jnp.float32), idx, n) \
+        .astype(g.dtype), None
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 # --------------------------------------------------------- grouped matmul
@@ -153,6 +151,80 @@ def grouped_matmul(lhs, rhs, group_sizes, *, tiling=None,
 
 # ------------------------------------------------------------------ layer
 
+def row_bound(T: int, k: int, n_held: int, n_experts: int) -> int:
+    """Rows of the sorted buffer on the common path: twice the rows that
+    T tokens are expected to send to n_held of n_experts,
+    T·k·n_held/n_experts, in whole `GMM_ROW_TILE`s, and at most
+    T·min(k, n_held), the most they can send."""
+    expected = -(-T * k * n_held // n_experts)
+    tiles = -(-2 * expected // GMM_ROW_TILE)
+    return min(tiles * GMM_ROW_TILE, T * min(k, n_held))
+
+
+def _experts(xs, weights, sizes):
+    """The held experts' SwiGLU over rows xs sorted by expert."""
+    with jax.named_scope("moe.experts"):
+        wg, wu, wo = weights
+        tiling = gmm_tiling(xs.shape[0], wg.shape[1], wg.shape[2])
+
+        def product(a, w):
+            return grouped_matmul(a, w, sizes, tiling=tiling)
+        return product(jax.nn.silu(product(xs, wg)) * product(xs, wu), wo)
+
+
+def _core(rows, xt, w, weights, order, sizes):
+    """y (T, D) from the first `rows` sorted rows, which hold every held
+    pair: rows xt (T, D), gate weights w (T, k) (0 where the expert is
+    not held), the experts' (wi_gate, wi_up, wo), the sorted pairs
+    `order` (T·k,) and the rows per group `sizes`. The rows are gathered
+    by token and their weighted products summed back into their tokens."""
+    T, k = w.shape
+    with jax.named_scope("moe.permute"):
+        pair = order[:rows]
+        token = pair // k
+        xs = _take_rows(xt, token, T)                          # (rows, D)
+    ys = _experts(xs, weights, sizes)
+    with jax.named_scope("moe.unpermute"):
+        weighted = ys.astype(jnp.float32) \
+            * w.reshape(-1)[pair].astype(jnp.float32)[:, None]
+        return jax.ops.segment_sum(weighted, token, T).astype(xt.dtype)
+
+
+def _cores(rows, w, weights):
+    """`_core` on every row the tokens can send and on `rows` rows, in
+    `lax.cond`'s order (true first)."""
+    T, k = w.shape
+    return (functools.partial(_core, T * min(k, weights[0].shape[0])),
+            functools.partial(_core, rows))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sized_core(rows, full, xt, w, weights, order, sizes):
+    """`_core` on every row where `full`, else on the first `rows`."""
+    return jax.lax.cond(full, *_cores(rows, w, weights),
+                        xt, w, weights, order, sizes)
+
+
+def _sized_core_fwd(rows, full, xt, w, weights, order, sizes):
+    res = (full, xt, w, weights, order, sizes)
+    return _sized_core(rows, *res), res
+
+
+def _sized_core_bwd(rows, res, g):
+    full, xt, w, weights, order, sizes = res
+
+    def grads(core):
+        def run(xt, w, weights):
+            return core(xt, w, weights, order, sizes)
+        return lambda: jax.vjp(run, xt, w, weights)[1](g)
+    dx, dw, dweights = jax.lax.cond(
+        full, *map(grads, _cores(rows, w, weights)))
+    return None, dx, dw, dweights, None, None
+
+
+_sized_core.defvjp(_sized_core_fwd, _sized_core_bwd)
+
+
 def moe_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig,
             compute_dtype=jnp.bfloat16):
     """x: (B, S, D) -> (y (B, S, D), stats).
@@ -160,8 +232,9 @@ def moe_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     y is the held experts' part of the layer's output. stats: "counts"
     (E,) and "probs" (E,), the (token, choice) pairs and the router
     probability each expert drew over the T tokens (the load-balancing
-    loss's sums), and "rows" (n_held,) int32, the rows each held expert
-    computed."""
+    loss's sums), "rows" (n_held,) int32, the rows each held expert
+    computed, and "full" int32, 1 where they were more than `row_bound`
+    and the call ran its core on every row the tokens can send."""
     B, S, D = x.shape
     T = B * S
     E, k = cfg.n_experts, cfg.experts_per_token
@@ -179,28 +252,20 @@ def moe_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig,
         local = idx.reshape(-1) - e0                           # (T*k,)
         group = jnp.where((local >= 0) & (local < Eh), local, Eh)
         order = jnp.argsort(group, stable=True).astype(jnp.int32)
-        inv = jnp.argsort(order).astype(jnp.int32)
         sizes = jnp.sum(group[:, None] == jnp.arange(Eh + 1), axis=0,
                         dtype=jnp.int32)
-        # at most min(k, Eh) of a token's choices are held here
-        M = T * min(k, Eh)
-        xs = _dispatch(xt, order, inv, k)[:M]                  # (M, D)
         stats["rows"] = sizes[:Eh]
-
-    with jax.named_scope("moe.experts"):
-        tiling = gmm_tiling(M, D, cfg.d_ff)
-
-        def gmm(a, w):
-            return grouped_matmul(a, w.astype(compute_dtype), sizes,
-                                  tiling=tiling)
-        g = gmm(xs, p["wi_gate"])
-        u = gmm(xs, p["wi_up"])
-        ys = gmm(jax.nn.silu(g) * u, p["wo"])                 # (M, D)
+        rows = row_bound(T, k, Eh, E)
+        full = jnp.sum(sizes[:Eh]) > rows
+        stats["full"] = full.astype(jnp.int32)
 
     with jax.named_scope("moe.unpermute"):
-        ys = jnp.pad(ys, ((0, T * k - M), (0, 0)))
-        pairs = _permute(ys, inv, order).reshape(T, k, D)
         held = (group < Eh).reshape(T, k)
         w = jnp.where(held, gate, 0.0).astype(compute_dtype)
-        y = jnp.einsum("tkd,tk->td", pairs, w)
+    weights = tuple(p[n].astype(compute_dtype)
+                    for n in ("wi_gate", "wi_up", "wo"))
+    if rows == T * min(k, Eh):              # no routing can exceed it
+        y = _core(rows, xt, w, weights, order, sizes)
+    else:
+        y = _sized_core(rows, full, xt, w, weights, order, sizes)
     return y.reshape(B, S, D), stats

@@ -127,7 +127,8 @@ class Trainer:
         self.state: Optional[dict] = None
         self.logs: list[StepLog] = []
         # the newest step's metrics (loss parts, the MoE layers'
-        # expert_rows), left on the device: nothing reads them per step
+        # expert_rows and expert_full), left on the device: nothing reads
+        # them per step
         self.last_metrics: Optional[dict] = None
         self.reports: list[RecoveryReport] = []
         self.straggler = StragglerTracker()

@@ -93,21 +93,92 @@ def test_the_shares_add_up_to_the_whole_layer():
     np.testing.assert_array_equal(rows, np.asarray(parts[0][1]["counts"]))
 
 
-def test_routing_is_dropless():
-    """A router that sends every token to one held expert: all of them
-    are computed (a capacity of 1.25 k T / E rows would drop most)."""
+def dense_mixture(p, x, cfg):
+    """(T, D): `plain_mixture` in jnp, for its gradients: every held
+    expert runs on every token and is weighted by the token's gate where
+    the token chose it."""
+    x = x.reshape(-1, cfg.d_model)
+    probs = jax.nn.softmax(x @ p["router"], axis=-1)
+    gate, top = jax.lax.top_k(probs, cfg.experts_per_token)
+    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    held = jnp.arange(cfg.n_held) + cfg.expert_offset
+    w = jnp.sum(jnp.where(top[:, :, None] == held, gate[:, :, None], 0.0),
+                axis=1)                                      # (T, held)
+    h = jax.nn.silu(jnp.einsum("td,edf->tef", x, p["wi_gate"])) \
+        * jnp.einsum("td,edf->tef", x, p["wi_up"])
+    return jnp.einsum("tef,efd,te->td", h, p["wo"], w)
+
+
+def _one_held_expert(router):
+    return router.at[:, TINY.expert_offset + 1].add(50.0)
+
+
+def _all_to_held(router):
+    held = jnp.arange(E) - TINY.expert_offset
+    return router + jnp.where((held >= 0) & (held < HELD), 0.1, -0.1)
+
+
+# T tokens of TINY send up to T * 4 rows to its 4 held experts of 16 and
+# are expected to send T; row_bound gives the layer 2 T rows in whole
+# tiles, which at T = 128 is a branch between 256 and 512 rows
+@pytest.mark.parametrize("routing,S,core", [
+    pytest.param(_one_held_expert, 8, "full", id="one-held-expert"),
+    pytest.param(None, 64, "bounded", id="balanced"),
+    pytest.param(_all_to_held, 64, "full-branch", id="all-to-held"),
+])
+def test_routing_is_dropless(routing, S, core):
+    """Every pair routed to a held expert is computed, and output and
+    gradients match the plain mixture, at each size of the core: every
+    row with no branch (a batch whose bound is every row it can send),
+    the bounded rows (balanced routing), and the full-size branch (every
+    pair sent here, more rows than the bound). A capacity of 1.25 k T / E
+    rows would drop most pairs of the first and last routings."""
     p = _params(TINY)
-    chosen = TINY.expert_offset + 1
-    p["router"] = p["router"].at[:, chosen].add(50.0)
-    x = jnp.abs(_x(TINY)) + 0.1           # positive: the column wins
+    x = _x(TINY, S=S)
+    if routing is not None:
+        p["router"] = routing(p["router"])
+        x = jnp.abs(x) + 0.1              # positive: the columns win
     T = x.shape[0] * x.shape[1]
+    bound = moe.row_bound(T, K, HELD, E)
+    assert (bound == T * min(K, HELD)) == (core == "full")
     with jax.default_matmul_precision("highest"):
         y, stats = moe.moe_mlp(p, x, TINY, jnp.float32)
-    assert int(stats["rows"][1]) == T
-    assert int(stats["rows"][1]) > 1.25 * K * T / E
+        cot = jax.random.normal(jax.random.PRNGKey(3), (T, TINY.d_model))
+        got = jax.grad(lambda p, x: jnp.sum(moe.moe_mlp(
+            p, x, TINY, jnp.float32)[0].reshape(T, -1) * cot), (0, 1))(p, x)
+        want = jax.grad(lambda p, x: jnp.sum(
+            dense_mixture(p, x, TINY) * cot), (0, 1))(p, x)
     np.testing.assert_allclose(np.asarray(y).reshape(T, -1),
                                plain_mixture(p, x, TINY), rtol=1e-4,
                                atol=1e-5)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(b))))
+    held = int(jnp.sum(stats["rows"]))
+    assert int(stats["full"]) == (held > bound) == (core == "full-branch")
+    if routing is _one_held_expert:
+        assert int(stats["rows"][1]) == T > 1.25 * K * T / E
+    if routing is _all_to_held:
+        assert held == T * K > 1.25 * K * T * HELD / E
+
+
+@pytest.mark.parametrize("T,held,bound,branch", [
+    (2 * 4096, 8, 8192, True),            # train.moe.ckpt: 32 tiles
+    (2 * 4096, 0, 2 * 4096 * 8, False),   # every expert held
+    (4, 8, 4 * 8, False),                 # a decode step of four requests
+])
+def test_row_bound_branches_only_where_it_cuts_the_buffer(T, held, bound,
+                                                          branch):
+    """Qwen3-30B-A3B's layer (top-8 of 128): the bound is twice the
+    expected held rows in whole tiles, at most every row the tokens can
+    send, and only a bound under that builds a branch."""
+    cfg = dataclasses.replace(QWEN3, experts_held=held)
+    assert moe.row_bound(T, 8, cfg.n_held, 128) == bound
+    p = jax.eval_shape(lambda: moe.moe_init(jax.random.PRNGKey(0), cfg,
+                                            jnp.bfloat16))
+    x = jax.ShapeDtypeStruct((1, T, cfg.d_model), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda p, x: moe.moe_mlp(p, x, cfg))(p, x)
+    assert (" cond[" in str(jaxpr)) == branch
 
 
 def test_experts_held_elsewhere_add_nothing():
@@ -161,12 +232,20 @@ def test_grouped_matmul_kernel_matches_ragged_dot():
                                    atol=1e-4)
 
 
-def test_the_model_takes_the_kernel_where_it_tiles(monkeypatch):
+@pytest.mark.parametrize("S,cores", [(16, 1), (64, 2)])
+def test_the_model_takes_the_kernel_where_it_tiles(monkeypatch, S, cores):
     """On a TPU the layer runs the Pallas kernel (forced here, in
-    interpret mode) and agrees with the ragged_dot path."""
+    interpret mode) and agrees with the ragged_dot path, forward and
+    backward: the core on every row with no branch (16 tokens), and the
+    branch with the bounded rows taken (64 tokens), whose group sizes
+    count the pairs of absent experts past its rows."""
     p = _params(TINY)
-    x = _x(TINY, S=16)
-    want, _ = moe.moe_mlp(p, x, TINY, jnp.float32)
+    x = _x(TINY, S=S)
+
+    def grads(p, x):
+        return jax.grad(lambda p, x: jnp.sum(jnp.sin(moe.moe_mlp(
+            p, x, TINY, jnp.float32)[0])), (0, 1))(p, x)
+    want = moe.moe_mlp(p, x, TINY, jnp.float32)[0], grads(p, x)
     calls = []
     inner = moe.grouped_matmul
 
@@ -175,10 +254,13 @@ def test_the_model_takes_the_kernel_where_it_tiles(monkeypatch):
         return inner(a, w, sizes, tiling=(32, a.shape[1], w.shape[2]),
                      interpret=True)
     monkeypatch.setattr(moe, "grouped_matmul", kernel)
-    got, _ = moe.moe_mlp(p, x, TINY, jnp.float32)
-    assert len(calls) == 3
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    jax.clear_caches()                  # no core traced without the kernel
+    got = moe.moe_mlp(p, x, TINY, jnp.float32)[0]
+    assert len(calls) == 3 * cores      # each core's three products
+    got = got, grads(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("m,k,n,platform,tiling", [
@@ -249,7 +331,8 @@ def test_trainer_keeps_the_step_metrics_on_the_device():
     tr = Trainer(model, data, AdamWConfig(), TrainConfig(
         total_steps=2, ckpt_every=100))
     tr.run()
-    rows = tr.last_metrics["expert_rows"]
-    assert isinstance(rows, jax.Array)
+    rows, full = (tr.last_metrics[k] for k in ("expert_rows", "expert_full"))
+    assert isinstance(rows, jax.Array) and isinstance(full, jax.Array)
     assert rows.shape == (TINY.n_layers, HELD)
+    assert full.shape == (TINY.n_layers,)
     assert float(tr.last_metrics["aux"]) > 0

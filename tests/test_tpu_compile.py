@@ -144,6 +144,79 @@ def test_grouped_matmul_forward_and_backward_compile(one_chip, d_in, d_out):
     assert any("gmm" in n.replace("tgmm", "") for n in named), named
 
 
+def _computations(text):
+    """{computation: its lines} of a compiled HLO module's text."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name:
+            comps[name].append(line)
+    return comps
+
+
+def _reached(comps, roots):
+    """The computations `roots` call, themselves included."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += [ref for line in comps[name]
+                     for ref in re.findall(r"%[\w.\-]+", line)
+                     if ref in comps]
+    return seen
+
+
+def test_moe_train_step_bounds_its_sorted_rows(one_chip, monkeypatch):
+    """The gradient of a reduced MoE model's loss for a v5e, with the
+    grouped-matmul kernel the layer takes on a TPU: 512 tokens, top-4 of
+    16 experts, 4 held, so the sorted rows are bounded at 1,024 of the
+    2,048 the tokens can send. The layer scan holds one conditional
+    forward and one backward (the full remat's recompute of the forward
+    is dead), no buffer of 2,048 rows lies outside their full-size
+    branches, and only the kernels match gmm_roofline's pattern."""
+    import dataclasses
+    import functools
+
+    from repro.configs import reduced
+    from repro.models import moe
+    from repro.models.model import Model
+    cfg = dataclasses.replace(
+        reduced(get_config("qwen3-moe-30b-a3b")), d_model=256, d_ff=384,
+        n_experts=16, experts_per_token=4, experts_held=4, expert_offset=4)
+    B, S, M = 2, 256, 2048
+    assert moe.row_bound(B * S, 4, 4, 16) == 1024
+    monkeypatch.setattr(moe, "gmm_tiling", functools.partial(
+        moe.gmm_tiling, platform="tpu"))
+    model = Model(cfg)
+    params = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
+                          model.abstract_params())
+    batch = {k: _shape(one_chip, (B, S), jnp.int32)
+             for k in ("tokens", "labels")}
+    text = _compiled_text(jax.value_and_grad(model.loss_fn, has_aux=True),
+                          params, batch)
+    comps = _computations(text)
+    branches = [re.search(r"branch_computations=\{([^}]*)\}", line)
+                .group(1).split(", ") for line in text.splitlines()
+                if " conditional(" in line]
+    assert len(branches) == 2, branches
+    full = _reached(comps, [b[-1] for b in branches])      # cond's true
+    wide = {name: [line for line in lines if f"[{M},{cfg.d_model}]" in line
+                   or f"[{M},{cfg.d_ff}]" in line]
+            for name, lines in comps.items()}
+    assert any(wide[name] for name in full)
+    outside = [line for name in comps if name not in full
+               for line in wide[name]]
+    assert not outside, outside[:3]
+    named = {line.split("=")[0].strip(): 'custom_call_target="tpu_' \
+             'custom_call"' in line for line in text.splitlines()
+             if re.match(r"\s*(ROOT )?%\S*gmm\S* = ", line)}
+    assert named and all(named.values()), named
+
+
 def test_selective_scan_compiles(one_chip):
     from repro.kernels.mamba_scan.kernel import selective_scan
     ds = FALCON_MAMBA.ssm_state
